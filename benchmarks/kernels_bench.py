@@ -1,7 +1,8 @@
-"""Kernel micro-benchmarks: Pallas (interpret) vs pure-jnp oracle wall time
-on CPU.  Interpret-mode timing is NOT TPU-representative — the quantity that
-matters is the FLOP/byte skip encoded in the kernel shapes, which is also
-reported.
+"""Kernel micro-benchmarks: Pallas vs pure-jnp oracle wall time.  The
+kernels run compiled on a TPU and in the Pallas interpreter on the CPU
+(``ops.interpret_mode``); CPU timings are not TPU-representative — there the
+quantity that matters is the FLOP/byte skip encoded in the kernel shapes,
+which is also reported.
 
 The cavity/graph inputs come from the same ExecutionPlan compiler the model
 uses (engine.build_execution_plan) instead of hand-packing, so the bench
@@ -39,10 +40,11 @@ def _block_inputs():
 
 def main():
     backends = parse_backends(sys.argv[1:])
+    interp = ops.interpret_mode()
 
     # RFC encode/decode
     x = jax.random.normal(jax.random.PRNGKey(0), (512, 256))
-    t_enc = time_fn(lambda a: ops.rfc_encode(a), x, iters=3)
+    t_enc = time_fn(lambda a: ops.rfc_encode(a, interpret=interp), x, iters=3)
     t_ref = time_fn(lambda a: ref.rfc_encode_ref(a), x, iters=3)
     emit("kernels/rfc_encode_pallas", t_enc, "")
     emit("kernels/rfc_encode_ref", t_ref, "")
@@ -57,7 +59,8 @@ def main():
     xt = jax.random.normal(jax.random.PRNGKey(2), (16, 128, C))
     t_k = time_fn(
         lambda a: ops.cavity_tconv(a, pa["wp"], pa["taps"], pa["inv_perm"],
-                                   bs.n_kept_filters), xt, iters=3)
+                                   bs.n_kept_filters, interpret=interp),
+        xt, iters=3)
     t_r = time_fn(lambda a: ref.cavity_tconv_ref(a, ra["tw"]), xt, iters=3)
     n_keep, K = pa["wp"].shape[1], bs.tkernel
     emit("kernels/cavity_tconv_pallas", t_k,
@@ -67,7 +70,8 @@ def main():
     # fused graph+spatial conv: plan-precomputed padded graph + gathered W
     xg = jax.random.normal(jax.random.PRNGKey(3),
                            (4, 64, cfg.gcn_joints, pa["Wk"].shape[1]))
-    t_k = time_fn(lambda a: ops.graph_sconv(a, pa["Gp"], pa["Wk"]), xg,
+    t_k = time_fn(lambda a: ops.graph_sconv(a, pa["Gp"], pa["Wk"],
+                                            interpret=interp), xg,
                   iters=3)
     t_r = time_fn(
         lambda a: ref.graph_sconv_ref(
@@ -119,10 +123,12 @@ def main():
                  f"V={V} density={dens:.2f}")
             emit(f"kernels/sconv_csr/{tname}/{tag}/csr_ref", t_c,
                  f"nnz_skip={(1 - dens) * 100:.0f}%")
-            t_d = time_fn(lambda a, g_=gj: ops.graph_sconv(a, g_, w),
+            t_d = time_fn(lambda a, g_=gj: ops.graph_sconv(a, g_, w,
+                                                             interpret=interp),
                           xg, iters=3)
             t_c = time_fn(
-                lambda a, e=ej, v=ev: ops.graph_sconv_csr(a, e, v, w),
+                lambda a, e=ej, v=ev: ops.graph_sconv_csr(a, e, v, w,
+                                                     interpret=interp),
                 xg, iters=3)
             emit(f"kernels/sconv_csr/{tname}/{tag}/dense_pallas", t_d,
                  f"V={V} density={dens:.2f}")
@@ -144,7 +150,7 @@ def main():
     if len(times) > 1:
         emit("kernels/backend_forward_ratio", 0.0,
              f"pallas/reference={times['pallas'] / times['reference']:.2f}x "
-             "(interpret-mode CPU; not TPU-representative)")
+             f"({jax.devices()[0].device_kind})")
 
 
 if __name__ == "__main__":

@@ -27,11 +27,13 @@ MODULES = [
     "dyn_sched",          # Table II   — Dyn-Mult-PE sizing
     "throughput",         # Tables IV/V — throughput & peak perf
     "kernels_bench",      # kernel micro-benchmarks
-    "roofline_report",    # §Roofline from the dry-run artifacts
 ]
 
 
 def main() -> None:
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="run one module (accepts 'kernels' for kernels_bench)")

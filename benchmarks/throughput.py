@@ -1,17 +1,13 @@
 """Paper Tables IV/V: throughput + peak-performance comparison.
 
-The paper reports 271.25 fps / 1142 GOP/s on an XCKU-115 FPGA.  We cannot
-measure TPU wall time in this container, so we derive the TPU-v5e-projected
-throughput from the model's analytic op counts and the pruning plan:
-
-    fps = peak_FLOPs × util / (GOPs per clip)
-
-using the paper's own accounting (GOP counted on the *dense* model, skips
-credited to the accelerator — the same convention behind 1142 GOP/s), and
-report the FLOP-reduction chain original → w/oC → +skip → +prune.
-The ``--backend`` axis adds *measured* clips/s for the execution engine's
-reference and pallas backends on the reduced config (interpret-mode CPU —
-relative structure, not TPU wall time).
+The paper reports 271.25 fps / 1142 GOP/s on an XCKU-115 FPGA.  This module
+reports the model's analytic op counts under the paper's own accounting
+(GOP counted on the *dense* model, skips credited to the accelerator — the
+same convention behind 1142 GOP/s): the FLOP-reduction chain original →
+w/oC → +skip → +prune.  The ``--backend`` axis adds *measured* clips/s for
+the execution engine's reference and pallas backends on the reduced config;
+rows tagged "interpret CPU" are CPU timings of the Pallas interpreter —
+relative structure, not TPU wall time.
 """
 from __future__ import annotations
 
@@ -22,7 +18,6 @@ import numpy as np
 from benchmarks.common import emit, parse_backends
 from repro.configs import get_config
 from repro.core.pruning.plan import build_prune_plan
-from repro.launch.mesh import PEAK_FLOPS_BF16
 
 PAPER = {
     "ours_fpga_fps": 271.25,
@@ -75,13 +70,6 @@ def main():
         emit(f"throughput/gop/{name}", 0.0,
              f"GOP={g:.2f} reduction={(1-g/g0)*100:.1f}%")
 
-    # TPU-v5e projection at a conservative 40% MFU on the pruned model
-    g_final = agcn_gops(**variants["woC+skip+prune"])
-    mfu = 0.40
-    fps = PEAK_FLOPS_BF16 * mfu / (g_final * 1e9)
-    emit("throughput/tpu_v5e_projected", 0.0,
-         f"fps={fps:.0f} vs paper FPGA {PAPER['ours_fpga_fps']} "
-         f"vs V100-skip {PAPER['v100_skip']}")
     # paper speedup table reproduction (their numbers, our ratio check)
     for k in ("2080ti_fps", "v100_fps", "2080ti_skip", "v100_skip"):
         emit(f"throughput/paper/{k}", 0.0,

@@ -15,8 +15,9 @@ Two backends implement the per-block ops:
               ``graph_sconv`` (graph matmul + 1×1 conv in one VMEM pass),
               packed ``cavity_tconv`` (kept-tap matmuls only), and RFC
               encode/decode between blocks as the inter-layer activation
-              format.  ``interpret=True`` runs the same BlockSpecs on CPU;
-              on TPU pass ``interpret=False`` and they compile.
+              format.  The plan derives interpret mode from the platform
+              (``ops.interpret_mode``): interpreted on the CPU, compiled
+              on a TPU — there is no option to choose it.
 
 The plan is a registered pytree: its arrays are jit arguments (so two
 plans built from the same config hit the same jit cache entry — no
@@ -46,6 +47,7 @@ from repro.core.agcn import adaptive
 from repro.core.agcn.graph import GraphTopology, dense_to_csr, get_topology
 from repro.core.pruning.plan import PrunePlan
 from repro.core.quant import quantize_q88
+from repro.distributed.sharding import shard_batch
 from repro.kernels import ops
 
 BACKENDS = ("reference", "pallas")
@@ -74,7 +76,8 @@ class BlockStatic:
 @dataclasses.dataclass(frozen=True)
 class PlanStatic:
     """Hashable whole-plan metadata — the jit-cache key of a compiled
-    ExecutionPlan (backend/interpret selection, C5 input skip, RFC
+    ExecutionPlan (backend selection, the platform-derived Pallas
+    ``interpret`` mode, C5 input skip, RFC
     inter-layer format flags, streaming shape constants, and the per-block
     ``BlockStatic`` tuple)."""
 
@@ -329,7 +332,7 @@ class PallasBackend:
 
     name = "pallas"
 
-    def __init__(self, interpret: bool = True):
+    def __init__(self, interpret: bool):
         self.interpret = interpret
 
     def spatial(self, x, ba, bs, ck=None):
@@ -341,24 +344,30 @@ class PallasBackend:
         if bs.use_ck:
             return _spatial_einsum(xg, ba, bs, ck=ck)
         if bs.sconv == "csr":
-            return ops.graph_sconv_csr(xg, ba["ell_idx"], ba["ell_val"],
-                                       ba["Wk"], interpret=self.interpret)
-        return ops.graph_sconv(xg, ba["Gp"], ba["Wk"],
-                               interpret=self.interpret)
+            return shard_batch(
+                lambda v, i, e, w: ops.graph_sconv_csr(
+                    v, i, e, w, interpret=self.interpret),
+                xg, ba["ell_idx"], ba["ell_val"], ba["Wk"])
+        return shard_batch(
+            lambda v, g, w: ops.graph_sconv(v, g, w,
+                                            interpret=self.interpret),
+            xg, ba["Gp"], ba["Wk"])
 
     def temporal(self, x, ba, bs):
         """Packed cavity tconv kernel over the flattened (N·V, T, C) rows —
         only the kept taps are issued (the paper's C2 FLOP skip)."""
-        N, T, V, C = x.shape
-        xb = jnp.transpose(x, (0, 2, 1, 3)).reshape(N * V, T, C)
-        out = ops.cavity_tconv(
-            xb, ba["wp"], ba["taps"], ba["inv_perm"],
-            num_filters=bs.n_kept_filters, kernel_size=bs.tkernel,
-            stride=bs.stride, interpret=self.interpret,
-        )                                            # (N*V, T_out, F_kept)
-        T_out = out.shape[1]
-        out = jnp.transpose(
-            out.reshape(N, V, T_out, -1), (0, 2, 1, 3))
+        def conv(x, wp, taps, inv):
+            N, T, V, C = x.shape
+            xb = jnp.transpose(x, (0, 2, 1, 3)).reshape(N * V, T, C)
+            out = ops.cavity_tconv(
+                xb, wp, taps, inv, num_filters=bs.n_kept_filters,
+                kernel_size=bs.tkernel, stride=bs.stride,
+                interpret=self.interpret,
+            )                                        # (N*V, T_out, F_kept)
+            return jnp.transpose(
+                out.reshape(N, V, out.shape[1], -1), (0, 2, 1, 3))
+
+        out = shard_batch(conv, x, ba["wp"], ba["taps"], ba["inv_perm"])
         out = out + ba["tb"]
         if bs.pruned_filters:
             out = _scatter_filters(out, ba["kept_filters"], bs.cout)
@@ -367,13 +376,17 @@ class PallasBackend:
     def temporal_step(self, win, ba, bs):
         """Single-timestep packed cavity tconv on a chronological window
         (N, K, V, C) — the same packed weights/taps, T_pad == K."""
-        N, K, V, C = win.shape
-        xb = jnp.transpose(win, (0, 2, 1, 3)).reshape(N * V, K, C)
-        out = ops.cavity_tconv_step(
-            xb, ba["wp"], ba["taps"], ba["inv_perm"],
-            num_filters=bs.n_kept_filters, interpret=self.interpret,
-        )                                             # (N*V, F_kept)
-        out = out.reshape(N, V, -1) + ba["tb"]
+        def conv(win, wp, taps, inv):
+            N, K, V, C = win.shape
+            xb = jnp.transpose(win, (0, 2, 1, 3)).reshape(N * V, K, C)
+            out = ops.cavity_tconv_step(
+                xb, wp, taps, inv, num_filters=bs.n_kept_filters,
+                interpret=self.interpret,
+            )                                         # (N*V, F_kept)
+            return out.reshape(N, V, -1)
+
+        out = shard_batch(conv, win, ba["wp"], ba["taps"], ba["inv_perm"])
+        out = out + ba["tb"]
         if bs.pruned_filters:
             out = _scatter_filters(out, ba["kept_filters"], bs.cout)
         return out
@@ -383,15 +396,29 @@ class PallasBackend:
         activation format (lossless on post-ReLU values)."""
         if not ps.use_rfc:
             return h
-        vals, hot = ops.rfc_encode(h, bank=ps.rfc_bank,
-                                   interpret=self.interpret)
-        return ops.rfc_decode(vals, hot, bank=ps.rfc_bank,
-                              interpret=self.interpret)
+        return _rfc_decode(_rfc_encode(h, ps), ps)
 
 
-def get_backend(name: str, interpret: bool = True) -> Backend:
+def _rfc_encode(h: jnp.ndarray, ps: PlanStatic):
+    """RFC-encode an inter-block activation with the plan's bank width:
+    ``(values, hot)``, one kernel call per device shard of the slot axis."""
+    return shard_batch(
+        lambda v: ops.rfc_encode(v, bank=ps.rfc_bank,
+                                 interpret=ps.interpret), h)
+
+
+def _rfc_decode(encoded, ps: PlanStatic) -> jnp.ndarray:
+    """Inverse of :func:`_rfc_encode` on its ``(values, hot)`` pair."""
+    return shard_batch(
+        lambda vh: ops.rfc_decode(*vh, bank=ps.rfc_bank,
+                                  interpret=ps.interpret), tuple(encoded))
+
+
+def get_backend(name: str, interpret: bool) -> Backend:
     """Backend registry lookup: ``reference`` | ``pallas`` (cheap to call
-    inside traced code — backends are stateless op providers)."""
+    inside traced code — backends are stateless op providers).
+    ``interpret`` is the plan's platform-derived Pallas mode
+    (``PlanStatic.interpret``); the reference backend ignores it."""
     if name == "reference":
         return ReferenceBackend()
     if name == "pallas":
@@ -431,7 +458,6 @@ def build_execution_plan(
     *,
     quant: bool = False,
     backend: str = "reference",
-    interpret: bool = True,
     use_rfc: Optional[bool] = None,
     topology: Optional[Any] = None,
     pad_joints: Optional[int] = None,
@@ -461,6 +487,10 @@ def build_execution_plan(
     (with zero ``csr_eps`` the learned dense B_k keeps every graph at
     density 1.0, so auto picks dense — today's path — until B_k is
     thresholded).
+
+    Pallas plans record ``interpret`` from the platform
+    (:func:`repro.kernels.ops.interpret_mode`): the CPU interprets the
+    kernels, a TPU compiles them, and any other platform raises.
     """
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}")
@@ -605,7 +635,8 @@ def build_execution_plan(
     if use_rfc is None:
         use_rfc = backend == "pallas"
     static = PlanStatic(
-        backend=backend, interpret=bool(interpret),
+        backend=backend,
+        interpret=backend == "pallas" and ops.interpret_mode(),
         input_skip=int(input_skip), use_rfc=bool(use_rfc),
         rfc_bank=int(cfg.rfc_bank), tkernel=int(cfg.gcn_tkernel),
         joints=int(V), in_channels=int(cfg.gcn_in_channels),
@@ -1268,9 +1299,10 @@ def step_frame(
                               sb["ck_ph"])
             vjs = vj if vmask else 0
             if ps.backend == "pallas":
-                ck = ops.windowed_similarity(ck_th, ck_ph,
-                                             valid_joints=vjs,
-                                             interpret=ps.interpret)
+                ck = shard_batch(
+                    lambda r: ops.windowed_similarity(
+                        *r, valid_joints=vjs, interpret=ps.interpret),
+                    (ck_th, ck_ph))
             else:
                 ck = adaptive.windowed_ck(ck_th.sum(axis=1),
                                           ck_ph.sum(axis=1),
@@ -1334,15 +1366,13 @@ def step_frame(
         # --- inter-block transfer: the RFC format, frame-wise -------------
         if b < nblocks - 1:
             if ps.use_rfc:
-                vals, hot = ops.rfc_encode(out, bank=ps.rfc_bank,
-                                           interpret=ps.interpret)
+                vals, hot = _rfc_encode(out, ps)
                 old = state.rfc[b]
                 keep = emit[:, None, None]
                 new_rfc.append(
                     {"vals": jnp.where(keep, vals, old["vals"]),
                      "hot": jnp.where(keep, hot, old["hot"])})
-                out = ops.rfc_decode(vals, hot, bank=ps.rfc_bank,
-                                     interpret=ps.interpret)
+                out = _rfc_decode((vals, hot), ps)
             h_in = out
         has_input = emit
         in_valid = out_valid

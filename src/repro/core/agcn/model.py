@@ -100,7 +100,6 @@ def forward(
     quant: bool = False,
     backend: Optional[str] = None,
     exec_plan=None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Logits (N, num_classes).
 
@@ -117,7 +116,7 @@ def forward(
         return engine.execute(exec_plan, x)
     name = backend or cfg.gcn_backend or "reference"
     ep = engine.build_execution_plan(
-        params, cfg, plan, quant=quant, backend=name, interpret=interpret)
+        params, cfg, plan, quant=quant, backend=name)
     return engine.execute(ep, x)
 
 
@@ -129,7 +128,6 @@ def init_stream(
     quant: bool = False,
     backend: Optional[str] = None,
     exec_plan=None,
-    interpret: bool = True,
 ):
     """State-init API for per-frame continual inference (engine streaming
     mode).  Returns ``(exec_plan, StreamState)``.
@@ -144,7 +142,7 @@ def init_stream(
     if ep is None:
         name = backend or cfg.gcn_backend or "reference"
         ep = engine.build_execution_plan(
-            params, cfg, plan, quant=quant, backend=name, interpret=interpret)
+            params, cfg, plan, quant=quant, backend=name)
     state = engine.init_stream_state(ep, x_calib.shape[0], x_calib=x_calib)
     return ep, state
 

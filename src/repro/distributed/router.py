@@ -2,8 +2,10 @@
 
 One mesh-sharded slab scales slot capacity; replicas scale *dispatch*
 throughput (each replica is its own service with its own compiled plans,
-slab and scheduler — on real hardware, its own device set).  The router
-in front of them owns three things:
+slab and scheduler).  :meth:`ReplicaRouter.build` pins replica *i* to
+device ``i mod D`` of the D visible devices through a one-device mesh, so
+on a four-chip host each replica's plans, slabs and snapshot rings live on
+its own chip.  The router in front of them owns three things:
 
 * **consistent pinning** — a session opened through the router gets a
   :class:`RouterHandle`; the router remembers which replica holds it, and
@@ -70,18 +72,36 @@ class ReplicaRouter:
         self.migration_failures = 0  # rebalance picks that had no mover
 
     @classmethod
-    def build(cls, cfg, *, replicas: int, **service_kwargs) -> "ReplicaRouter":
+    def build(cls, cfg, *, replicas: int,
+              **service_kwargs) -> "ReplicaRouter":
         """Build ``replicas`` services for one router: the first compiles
         its ExecutionPlans and BN calibration, the rest share them (plans
-        are immutable pytrees; slabs/schedulers stay per-replica)."""
+        are immutable pytrees; slabs/schedulers stay per-replica).
+
+        Replica *i* is placed on visible device ``i mod D`` through a
+        one-device mesh — its plans, BN stats, slabs and snapshot rings
+        are committed there and every tick it dispatches runs there."""
+        import jax
+        from jax.sharding import Mesh
+
+        from repro.distributed.serving import BATCH_AXIS
+
         if replicas < 1:
             raise ValueError(f"replicas must be >= 1, got {replicas}")
-        first = GcnService(cfg, **service_kwargs)
+        if service_kwargs.pop("mesh", None) is not None:
+            raise ValueError("router replicas are placed one per device; "
+                             "a per-replica mesh is not supported")
+        devs = jax.devices()
+
+        def on(i: int) -> Mesh:
+            return Mesh(np.asarray([devs[i % len(devs)]]), (BATCH_AXIS,))
+
+        first = GcnService(cfg, mesh=on(0), **service_kwargs)
         service_kwargs.pop("plans", None)
         service_kwargs.pop("bn_stats", None)
         rest = [GcnService(cfg, plans=first.plans, bn_stats=first.bn_stats,
-                           **service_kwargs)
-                for _ in range(replicas - 1)]
+                           mesh=on(i), **service_kwargs)
+                for i in range(1, replicas)]
         return cls([first] + rest)
 
     # -- placement ------------------------------------------------------------

@@ -84,20 +84,20 @@ def collective_cost_ms(svc, iters: int = 16) -> float:
     zf = jnp.zeros((S, svc.vmax, svc.cfg.gcn_in_channels))
     zb = jnp.zeros((S,), bool)
 
-    def timed(step, slabs) -> float:
-        out = step(svc.plans, slabs, zf, zb, zb, zb)   # compile + warm
+    def timed(step, plans, slabs) -> float:
+        out = step(plans, slabs, zf, zb, zb, zb)   # compile + warm
         jax.block_until_ready(out[1])
         t0 = time.perf_counter()
         for _ in range(iters):
-            out = step(svc.plans, slabs, zf, zb, zb, zb)
+            out = step(plans, slabs, zf, zb, zb, zb)
         jax.block_until_ready(out[1])
         return (time.perf_counter() - t0) / iters * 1e3
 
-    sharded_ms = timed(svc._step, svc.slabs)
+    sharded_ms = timed(svc._step, svc.plans, svc.slabs)
     dev = jax.devices()[0]
     single = jax.jit(make_gcn_slab_step(svc.cfg))
-    slabs1 = jax.device_put(svc.slabs, dev)
-    single_ms = timed(single, slabs1)
+    plans1, slabs1 = jax.device_put((svc.plans, svc.slabs), dev)
+    single_ms = timed(single, plans1, slabs1)
     return max(0.0, sharded_ms - single_ms)
 
 

@@ -94,6 +94,29 @@ def named_sharding(*axes: Optional[str]) -> Optional[NamedSharding]:
     return NamedSharding(_CTX.mesh, logical_spec(*axes))
 
 
+def shard_batch(fn, batched, *consts):
+    """``fn(batched, *consts)``, run once per device shard of the leading
+    (logical ``"batch"``) axis of every leaf of ``batched`` when the active
+    rules shard that axis over more than one device; ``consts`` (weights)
+    are replicated.  XLA cannot partition a compiled Pallas (Mosaic)
+    kernel, so each kernel call inside a sharded step runs under
+    ``shard_map`` this way — every device on its own slots.  Outside the
+    rules, on one device, or when the axis does not divide, it is a plain
+    call."""
+    spec = _resolve("batch") if _CTX.mesh is not None else None
+    if spec is None:
+        return fn(batched, *consts)
+    n = 1
+    for a in (spec if isinstance(spec, tuple) else (spec,)):
+        n *= _CTX.mesh.shape[a]
+    lead = {leaf.shape[0] for leaf in jax.tree_util.tree_leaves(batched)}
+    if n == 1 or any(d % n for d in lead):
+        return fn(batched, *consts)
+    return jax.shard_map(
+        fn, mesh=_CTX.mesh, in_specs=(P(spec),) + (P(),) * len(consts),
+        out_specs=P(spec), check_vma=False)(batched, *consts)
+
+
 def current_mesh() -> Optional[Mesh]:
     return _CTX.mesh
 

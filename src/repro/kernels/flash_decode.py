@@ -74,7 +74,8 @@ def flash_decode_pallas(
     k: jnp.ndarray,        # (B, S, Hkv, D)
     v: jnp.ndarray,        # (B, S, Hkv, D)
     valid: jnp.ndarray,    # scalar int32
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Fused GQA decode attention with online softmax: one query token per
     (batch, kv-head, group) against a ``valid``-masked KV cache — q

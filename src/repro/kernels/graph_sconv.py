@@ -2,20 +2,24 @@
 (paper C1, eq. (5)).
 
 Computes   out = Σ_k (G_k · x) · W_k   in one VMEM pass: the graph matmul
-(V×V, V=25 padded to 32 lanes) and the pruned 1×1 conv share the x tile, so
-the intermediate (G·x) never round-trips to HBM — the TPU analogue of the
-paper's on-chip dataflow where graph results feed Mult-PEs directly.
+(V×V, V=25 padded to 32 sublanes) and the pruned 1×1 conv share the x
+tile, so the intermediate (G·x) never round-trips to HBM — the TPU analogue
+of the paper's on-chip dataflow where graph results feed Mult-PEs directly.
 
 Channel compaction happens in ops.py (kept channels gathered before the
 call), so Cin here is the *kept* channel count — the graph-skip is already
-realised in the shapes.
+realised in the shapes.  Both matmuls are plain dots the TPU compiler
+lowers at any channel width: the graph matmul is a row-batched
+(Vp×Vp)·(Vp×Cin) dot, and the 1×1 conv one (r·Vp×Cin)·(Cin×co) dot whose
+row merge is free because Vp is a sublane multiple.
 
 Layouts:
-  x:   (R, V, Cin)    rows = N*T (flattened batch×time)
-  g:   (K, V, V)      static + learned graph, padded to Vp
+  x:   (R, Vp, Cin)   rows = N*T (flattened batch×time)
+  g:   (K, Vp, Vp)    static + learned graph, padded to Vp
   w:   (K, Cin, Cout)
-  out: (R, V, Cout)
-Grid: (R tiles, Cout tiles); K is a static in-kernel loop.
+  out: (R, Vp, Cout)
+Grid: (R tiles, Cout tiles); K is a static in-kernel loop.  The row tile
+is chosen from a VMEM budget (:func:`row_tile`), not fixed.
 """
 from __future__ import annotations
 
@@ -25,27 +29,57 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-R_TILE = 128
 CO_TILE = 128
+VMEM_BUDGET = 8 * 2 ** 20     # half the default scoped VMEM of a v5e core
+MAX_ROW_TILE = 256
+
+
+def _lanes(n: int) -> int:
+    return -(-n // 128) * 128
+
+
+def row_tile(vp: int, cin: int, co: int) -> int:
+    """Largest power-of-two row tile whose VMEM footprint fits the budget:
+    double-buffered x and out blocks, the broadcast graph, the graph-matmul
+    result and the f32 accumulator, each lane-padded to 128."""
+    per_row = 4 * vp * (3 * _lanes(cin) + 4 * _lanes(co) + _lanes(vp))
+    r = MAX_ROW_TILE
+    while r > 8 and r * per_row > VMEM_BUDGET:
+        r //= 2
+    return r
+
+
+def _graph_conv(x, g_of_k, w_ref, kv: int):
+    """Σ_k (G_k·x)·W_k on one (r, Vp, Cin) tile; ``g_of_k(k)`` yields the
+    (Vp, Vp) graph of subset k.  Returns the (r·Vp, co) f32 accumulator."""
+    r, vp, cin = x.shape
+    acc = jnp.zeros((r * vp, w_ref.shape[-1]), jnp.float32)
+    for k in range(kv):                             # K_v = 3, static
+        gb = jnp.broadcast_to(g_of_k(k), (r, vp, vp))
+        # graph matmul, batched over rows: y[r, w, c] = Σ_v g[w, v] x[r, v, c]
+        y = jax.lax.dot_general(
+            gb, x, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        acc += jnp.dot(y.reshape(r * vp, cin), w_ref[k],
+                       preferred_element_type=jnp.float32)
+    return acc
 
 
 def _kernel(x_ref, g_ref, w_ref, out_ref, *, kv: int):
-    x = x_ref[...]                                  # (r, Vp, Cin)
-    acc = jnp.zeros(out_ref.shape, jnp.float32)
-    for k in range(kv):                             # K_v = 3, static
-        gk = g_ref[k]                               # (Vp, Vp)
-        # graph matmul: y[r, w, c] = sum_v gk[w, v] * x[r, v, c]
-        y = jax.lax.dot_general(
-            gk, x, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                           # (Vp, r, Cin)
-        y = jnp.transpose(y, (1, 0, 2))             # (r, Vp, Cin)
-        wk = w_ref[k]                               # (Cin, co)
-        acc += jax.lax.dot_general(
-            y, wk, (((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-    out_ref[...] = acc.astype(out_ref.dtype)
+    x = x_ref[...]
+    acc = _graph_conv(x, lambda k: g_ref[k], w_ref, kv)
+    out_ref[...] = acc.reshape(out_ref.shape).astype(out_ref.dtype)
+
+
+def _grid(R: int, Vp: int, Cin: int, Cout: int):
+    co_tile = CO_TILE if Cout % CO_TILE == 0 else Cout
+    r_tile = min(row_tile(Vp, Cin, co_tile), R)
+    if R % r_tile:
+        raise ValueError(
+            f"row axis R={R} is not a multiple of the row tile {r_tile}; "
+            f"pad the flattened N*T axis (ops.graph_sconv does this)")
+    return (R // r_tile, Cout // co_tile), r_tile, co_tile
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -53,7 +87,8 @@ def graph_sconv_pallas(
     x: jnp.ndarray,      # (R, Vp, Cin)
     g: jnp.ndarray,      # (K, Vp, Vp)
     w: jnp.ndarray,      # (K, Cin, Cout)
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Fused Σ_k (G_k·x)·W_k in one VMEM pass: (R, Vp, Cin) -> (R, Vp, Cout).
 
@@ -62,17 +97,7 @@ def graph_sconv_pallas(
     the (R tiles, Cout tiles) grid divides exactly."""
     R, Vp, Cin = x.shape
     K, _, Cout = w.shape
-    if R % R_TILE == 0:
-        r_tile = R_TILE
-    elif R <= R_TILE:
-        r_tile = R                      # single row tile (small batches)
-    else:
-        raise ValueError(
-            f"row axis R={R} exceeds one tile but is not a multiple of "
-            f"R_TILE={R_TILE}; pad the flattened N*T axis (ops.graph_sconv "
-            f"does this) so the grid divides")
-    co_tile = CO_TILE if Cout % CO_TILE == 0 else Cout
-    grid = (R // r_tile, Cout // co_tile)
+    grid, r_tile, co_tile = _grid(R, Vp, Cin, Cout)
 
     in_spec = pl.BlockSpec((r_tile, Vp, Cin), lambda r, c: (r, 0, 0))
     g_spec = pl.BlockSpec((K, Vp, Vp), lambda r, c: (0, 0, 0))
@@ -86,24 +111,28 @@ def graph_sconv_pallas(
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((R, Vp, Cout), x.dtype),
         interpret=interpret,
+        name="graph_sconv",
     )(x, g, w)
 
 
-def _csr_kernel(x_ref, idx_ref, val_ref, w_ref, out_ref, *, kv: int, deg: int):
-    x = x_ref[...]                                  # (r, Vp, Cin)
-    acc = jnp.zeros(out_ref.shape, jnp.float32)
-    for k in range(kv):                             # static subset loop
-        agg = jnp.zeros(x.shape, jnp.float32)
+def _csr_kernel(x_ref, idx_ref, val_ref, w_ref, out_ref, *, kv: int,
+                deg: int):
+    x = x_ref[...]
+    vp = x.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, (vp, vp), 1)
+
+    def ell_graph(k):
+        # ELL -> (Vp, Vp) graph: one one-hot row scatter per ELL slot, so
+        # the neighbour gather runs on the MXU inside the graph matmul
+        ids = idx_ref[k]                            # (Vp, D)
+        vals = val_ref[k].astype(jnp.float32)
+        g = jnp.zeros((vp, vp), jnp.float32)
         for d in range(deg):                        # static ELL-slot loop
-            ids = idx_ref[k, :, d]                  # (Vp,) neighbor of row w
-            vals = val_ref[k, :, d]                 # (Vp,) edge weight (0=pad)
-            agg = agg + jnp.take(x, ids, axis=1) * vals[None, :, None]
-        wk = w_ref[k]                               # (Cin, co)
-        acc += jax.lax.dot_general(
-            agg, wk, (((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-    out_ref[...] = acc.astype(out_ref.dtype)
+            g = g + jnp.where(ids[:, d:d + 1] == col, vals[:, d:d + 1], 0.0)
+        return g.astype(x.dtype)
+
+    acc = _graph_conv(x, ell_graph, w_ref, kv)
+    out_ref[...] = acc.reshape(out_ref.shape).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -112,30 +141,21 @@ def graph_sconv_csr_pallas(
     idx: jnp.ndarray,      # (K, Vp, D) int32 ELL neighbor indices
     val: jnp.ndarray,      # (K, Vp, D) f32 edge weights, zero-padded
     w: jnp.ndarray,        # (K, Cin, Cout)
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Sparse Σ_k (G_k·x)·W_k over an ELL-packed graph.
 
-    The graph matmul is replaced by D gather-accumulate sweeps (D = max row
-    degree, from ops.pack_csr_ell): each sweep pulls one neighbor per output
-    joint and scales by its edge weight, so compute follows nnz instead of
-    Vp² — the win for the near-empty two-person / hand graphs.  Grid and
+    The graph crosses HBM as its ELL pack (D = max row degree, from
+    ops.pack_csr_ell) and is expanded in VMEM into a one-hot (Vp, Vp)
+    matrix — D compare-selects per subset — so the neighbour gather is an
+    MXU matmul; the TPU has no vector gather across sublanes.  Grid and
     tiling mirror :func:`graph_sconv_pallas`; idx/val ride whole in VMEM.
     """
     R, Vp, Cin = x.shape
     K, _, Cout = w.shape
     D = idx.shape[-1]
-    if R % R_TILE == 0:
-        r_tile = R_TILE
-    elif R <= R_TILE:
-        r_tile = R
-    else:
-        raise ValueError(
-            f"row axis R={R} exceeds one tile but is not a multiple of "
-            f"R_TILE={R_TILE}; pad the flattened N*T axis (ops.graph_sconv_csr "
-            f"does this) so the grid divides")
-    co_tile = CO_TILE if Cout % CO_TILE == 0 else Cout
-    grid = (R // r_tile, Cout // co_tile)
+    grid, r_tile, co_tile = _grid(R, Vp, Cin, Cout)
 
     in_spec = pl.BlockSpec((r_tile, Vp, Cin), lambda r, c: (r, 0, 0))
     idx_spec = pl.BlockSpec((K, Vp, D), lambda r, c: (0, 0, 0))
@@ -150,4 +170,5 @@ def graph_sconv_csr_pallas(
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((R, Vp, Cout), x.dtype),
         interpret=interpret,
+        name="graph_sconv_csr",
     )(x, idx, val, w)

@@ -2,24 +2,57 @@
 
 These handle layout adaptation (padding, filter-group permutation, kept-tap
 packing) so callers use natural shapes; the kernels see hardware-aligned
-tiles.  ``interpret`` defaults to True because this container is CPU-only —
-on TPU pass interpret=False and the same BlockSpecs compile.
+tiles.  Every wrapper takes ``interpret`` explicitly: the execution plan
+derives it once from the platform (:func:`interpret_mode`) — the Pallas
+interpreter on the CPU, compiled Mosaic kernels on a TPU.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
+import re
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.cavity_tconv import (cavity_tconv_pallas,
+from repro.kernels.cavity_tconv import (B_TILE, cavity_tconv_pallas,
                                         cavity_tconv_step_pallas)
-from repro.kernels.graph_sconv import (graph_sconv_csr_pallas,
-                                       graph_sconv_pallas)
-from repro.kernels.rfc_pack import rfc_decode_pallas, rfc_encode_pallas
+from repro.kernels.graph_sconv import (CO_TILE, graph_sconv_csr_pallas,
+                                       graph_sconv_pallas, row_tile)
+from repro.kernels.rfc_pack import (ROW_TILE, rfc_decode_pallas,
+                                    rfc_encode_pallas)
 from repro.kernels.window_sim import windowed_similarity_pallas
+
+
+def interpret_mode(platform: Optional[str] = None) -> bool:
+    """Whether Pallas kernels run in the interpreter, from the platform
+    (default: JAX's default backend).  The CPU has no Mosaic compiler, so
+    it interprets; a TPU compiles.  Any other platform has no Pallas path
+    here and raises rather than silently interpreting."""
+    platform = platform or jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise ValueError(f"no Pallas kernel path for platform {platform!r} "
+                     f"(expected 'cpu' or 'tpu')")
+
+
+_KERNEL_OP = re.compile(r'op_name="[^"]*/(\w+)/pallas_call"')
+
+
+def kernel_counts(hlo_text: str) -> Dict[str, int]:
+    """Compiled Pallas kernels per kernel name (``graph_sconv``,
+    ``cavity_tconv``, ``rfc_encode`` …) in a compiled program's HLO text —
+    each ``tpu_custom_call`` is one Mosaic kernel; interpreted kernels
+    leave none."""
+    counts: Dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            m = _KERNEL_OP.search(line)
+            name = m.group(1) if m else "?"
+            counts[name] = counts.get(name, 0) + 1
+    return counts
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
@@ -36,27 +69,31 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
 # RFC
 # ---------------------------------------------------------------------------
 
-def rfc_encode(x: jnp.ndarray, bank: int = 16, interpret: bool = True):
+def _pad_rfc(x: jnp.ndarray, bank: int) -> jnp.ndarray:
+    """(..., C) -> (rows, C') with C' a bank multiple and rows whole tiles."""
+    flat = _pad_to(x.reshape(-1, x.shape[-1]), 1, bank)
+    return _pad_to(flat, 0, ROW_TILE if flat.shape[0] > ROW_TILE else 8)
+
+
+def rfc_encode(x: jnp.ndarray, bank: int = 16, *, interpret: bool):
     """Encode activations of any (..., C) shape; returns (values, hot)."""
     shape = x.shape
-    flat = x.reshape(-1, shape[-1])
-    rows = flat.shape[0]
-    flat = _pad_to(_pad_to(flat, 1, bank), 0, 8)
-    vals, hot = rfc_encode_pallas(flat, bank=bank, interpret=interpret)
+    rows = int(np.prod(shape[:-1]))
+    vals, hot = rfc_encode_pallas(_pad_rfc(x, bank), bank=bank,
+                                  interpret=interpret)
     vals = vals[:rows, : shape[-1]].reshape(shape)
     hot = hot[:rows, : shape[-1]].reshape(shape)
     return vals, hot
 
 
-def rfc_decode(values: jnp.ndarray, hot: jnp.ndarray, bank: int = 16,
-               interpret: bool = True) -> jnp.ndarray:
+def rfc_decode(values: jnp.ndarray, hot: jnp.ndarray, bank: int = 16, *,
+               interpret: bool) -> jnp.ndarray:
     """Inverse of :func:`rfc_encode`: scatter each bank's front-packed
     values back to their hot positions.  Any (..., C) shape; lossless on
     post-ReLU activations (the roundtrip contract in test_rfc_format)."""
     shape = values.shape
-    v = _pad_to(_pad_to(values.reshape(-1, shape[-1]), 1, bank), 0, 8)
-    h = _pad_to(_pad_to(hot.reshape(-1, shape[-1]), 1, bank), 0, 8)
-    out = rfc_decode_pallas(v, h, bank=bank, interpret=interpret)
+    out = rfc_decode_pallas(_pad_rfc(values, bank), _pad_rfc(hot, bank),
+                            bank=bank, interpret=interpret)
     return out[: int(np.prod(shape[:-1])), : shape[-1]].reshape(shape)
 
 
@@ -107,27 +144,31 @@ def cavity_tconv(
     num_filters: int,
     kernel_size: int = 9,
     stride: int = 1,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Cavity-pruned temporal conv, 'same' padding.  Returns (B, T_out, F).
 
-    T_out follows conv semantics, ``(T + 2·pad − K)//stride + 1`` — for a
-    stride that doesn't divide the window count (odd T into a stride-2
-    block) the right pad is extended with zeros so the kernel's in-bounds
-    floor count equals it; otherwise reference and pallas would disagree
-    by one trailing output (and streaming parity with them)."""
+    T_out follows conv semantics, ``(T + 2·pad − K)//stride + 1``.  The
+    kernel computes a sublane multiple of outputs, so the right pad is
+    extended with zeros until every tap window of that many outputs is in
+    bounds; the surplus outputs are sliced off here (for a stride that
+    doesn't divide the window count this also keeps reference and pallas —
+    and streaming parity with them — on the same trailing output)."""
     pad = kernel_size // 2
-    T = x.shape[1]
+    B, T, _ = x.shape
     t_out = (T + 2 * pad - kernel_size) // stride + 1
-    # kernel needs K-1 + t_out·stride rows; ≥ T + 2·pad, equal iff divisible
-    t_pad = kernel_size - 1 + t_out * stride
+    t_blk = -(-t_out // 8) * 8
+    # the last tap window of t_blk outputs ends at K-1 + t_blk·stride
+    t_pad = kernel_size - 1 + t_blk * stride
     xp = jnp.pad(x, ((0, 0), (pad, t_pad - T - pad), (0, 0)))
+    xp = _pad_to(xp, 0, B_TILE if B > B_TILE else 8)
     out = cavity_tconv_pallas(
-        xp, wp, taps, kernel_size=kernel_size, stride=stride,
-        interpret=interpret,
-    )                                                 # (B, T_out, L, Fg)
-    B, T_out, L, Fg = out.shape
-    flat = out.reshape(B, T_out, L * Fg)
+        xp, wp, taps, t_out=t_blk, stride=stride, interpret=interpret,
+    )                                                 # (L, Bp, t_blk, Fg)
+    L, _, _, Fg = out.shape
+    flat = jnp.transpose(out, (1, 2, 0, 3))[:B, :t_out]
+    flat = flat.reshape(B, t_out, L * Fg)
     flat = jnp.take(flat, jnp.asarray(inv_perm), axis=-1)
     return flat[..., :num_filters]
 
@@ -138,7 +179,8 @@ def cavity_tconv_step(
     taps: jnp.ndarray,
     inv_perm: np.ndarray,
     num_filters: int,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Single-timestep cavity tconv over a full window.  Returns (B, F).
 
@@ -146,9 +188,11 @@ def cavity_tconv_step(
     holds K frames — ring-buffer zeros stand in for the clip's 'same'
     padding) and no stride (emission gating lives in the engine).  Same
     packed weights / tap sets / filter permutation as :func:`cavity_tconv`."""
-    out = cavity_tconv_step_pallas(x, wp, taps, interpret=interpret)
-    B, L, Fg = out.shape
-    flat = out.reshape(B, L * Fg)
+    B = x.shape[0]
+    xp = _pad_to(x, 0, B_TILE if B > B_TILE else 8)
+    out = cavity_tconv_step_pallas(xp, wp, taps, interpret=interpret)
+    L, _, Fg = out.shape                              # (L, Bp, Fg)
+    flat = jnp.transpose(out, (1, 0, 2))[:B].reshape(B, L * Fg)
     flat = jnp.take(flat, jnp.asarray(inv_perm), axis=-1)
     return flat[:, :num_filters]
 
@@ -161,7 +205,8 @@ def windowed_similarity(
     ring_th: jnp.ndarray,    # (S, K, V, Ce) per-slot θ-embedding ring
     ring_ph: jnp.ndarray,    # (S, K, V, Ce) per-slot φ-embedding ring
     valid_joints: int = 0,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Streaming windowed C_k from the embedding rings.  Returns (S, V, V).
 
@@ -183,17 +228,17 @@ def windowed_similarity(
 # Fused graph + spatial conv
 # ---------------------------------------------------------------------------
 
-def _pad_rows(x: jnp.ndarray):
+def _pad_rows(x: jnp.ndarray, cout: int):
     """Flatten (N, T, V, Cin) to kernel rows: joints sublane-aligned, N*T
-    padded to whole row tiles.  Returns (xr, R, Vp)."""
-    from repro.kernels.graph_sconv import R_TILE
-
+    padded to whole row tiles of the kernel's VMEM-budgeted row tile.
+    Returns (xr, R, Vp)."""
     N, T, V, Cin = x.shape
     Vp = ((V + 7) // 8) * 8                          # sublane-align joints
     R = N * T
+    rt = row_tile(Vp, Cin, CO_TILE if cout % CO_TILE == 0 else cout)
     xr = _pad_to(x.reshape(R, V, Cin), 1, 8)
     # row axis: whole tiles when more than one, else one 8-aligned tile
-    xr = _pad_to(xr, 0, R_TILE if R > R_TILE else 8)
+    xr = _pad_to(xr, 0, rt if R > rt else 8)
     return xr, R, Vp
 
 
@@ -201,7 +246,8 @@ def graph_sconv(
     x: jnp.ndarray,          # (N, T, V, Cin) — kept channels already gathered
     g: jnp.ndarray,          # (K, V, V) or prepadded (K, Vp, Vp) from a plan
     w: jnp.ndarray,          # (K, Cin, Cout)
-    interpret: bool = True,
+    *,
+    interpret: bool,
     topology: str = "",
 ) -> jnp.ndarray:
     """Fused Σ_k (G_k·x)·W_k.  Returns (N, T, V, Cout).
@@ -217,7 +263,7 @@ def graph_sconv(
     mismatched-shape errors so mixed-slab bugs name the offending skeleton.
     """
     N, T, V, Cin = x.shape
-    xr, R, Vp = _pad_rows(x)
+    xr, R, Vp = _pad_rows(x, w.shape[-1])
     note = f" for topology {topology!r}" if topology else ""
     if g.shape[0] != w.shape[0]:
         raise ValueError(
@@ -270,7 +316,8 @@ def graph_sconv_csr(
     idx: jnp.ndarray,        # (K, Vp', D) ELL indices, Vp' >= roundup8(V)
     val: jnp.ndarray,        # (K, Vp', D) ELL values
     w: jnp.ndarray,          # (K, Cin, Cout)
-    interpret: bool = True,
+    *,
+    interpret: bool,
     topology: str = "",
 ) -> jnp.ndarray:
     """Sparse Σ_k (G_k·x)·W_k over an ELL-packed graph.  Returns
@@ -281,7 +328,7 @@ def graph_sconv_csr(
     exact because padded rows are all-zero and indices only reference valid
     joints."""
     N, T, V, Cin = x.shape
-    xr, R, Vp = _pad_rows(x)
+    xr, R, Vp = _pad_rows(x, w.shape[-1])
     note = f" for topology {topology!r}" if topology else ""
     if idx.shape[0] != w.shape[0]:
         raise ValueError(

@@ -1,19 +1,26 @@
 """Pallas TPU kernels for RFC encode/decode (paper §V-C → DESIGN.md §2).
 
-TPU-native formulation: in-bank compaction is a *permutation*, and a 16×16
-permutation is a tiny matmul — so instead of a sort (which lowers poorly to
-the VPU) we build the one-hot compaction matrix from a cumulative sum of the
-hot mask and contract with it.  All lane accesses stay aligned; the bank
-width 16 maps onto the VREG lane dimension, mirroring the paper's
-"one-cycle aligned access" property.
+TPU-native formulation: in-bank compaction is a *permutation*, and
+permutations are matmuls — so instead of a sort (which lowers poorly to the
+VPU) every step is a 2-D dot on the MXU:
+
+  * the in-bank position of each non-zero is ``hot @ U`` with ``U`` the
+    block-diagonal upper-triangular ones matrix (a per-bank inclusive
+    prefix count, exact in any precision: 0/1 inputs, sums ≤ bank);
+  * the compaction sums, per target slot q, the one value of each bank
+    whose position is q: ``where(pos == q, x, 0) @ S`` with ``S`` the
+    block-diagonal same-bank ones matrix, kept at the lanes ``≡ q (mod
+    bank)``.  These dots run at HIGHEST precision, so each output is its
+    input value exactly (one non-zero term per sum).
+
+All tiles are 2-D (rows, columns), so no lane-splitting reshape reaches
+the compiler; the bank width 16 divides the 128-lane vreg, mirroring the
+paper's "one-cycle aligned access" property.
 
 Layouts:
   x:       (rows, C)            activations, C % bank == 0
   values:  (rows, C)            compacted banks (front-packed, zero padded)
   hot:     (rows, C) float mask (1.0 where the ReLU output was non-zero)
-
-``interpret=True`` is used on CPU (this container); on TPU the same kernels
-compile with the BlockSpecs below.
 """
 from __future__ import annotations
 
@@ -25,51 +32,78 @@ from jax.experimental import pallas as pl
 
 BANK = 16
 ROW_TILE = 256
-COL_TILE = 256
+COL_TILE = 128
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _bank_mats(cols: int, bank: int):
+    """(U, S): block-diagonal inclusive-prefix and same-bank ones matrices."""
+    i = jax.lax.broadcasted_iota(jnp.int32, (cols, cols), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (cols, cols), 1)
+    same = (i // bank) == (j // bank)
+    upper = jnp.where(same & (i <= j), 1.0, 0.0).astype(jnp.float32)
+    return upper, same.astype(jnp.float32)
+
+
+def _positions(hot, upper):
+    """Slot of each element inside its bank's compacted stream (-1 = cold)."""
+    pos = jnp.dot(hot, upper, preferred_element_type=jnp.float32) - 1.0
+    return jnp.where(hot > 0.0, pos, -1.0)
 
 
 def _encode_kernel(x_ref, vals_ref, hot_ref, *, bank: int):
-    x = x_ref[...]
-    rows, cols = x.shape
-    x = jnp.maximum(x, 0.0)                       # fused ReLU (paper: encode
-    b = x.reshape(rows, cols // bank, bank)       #  is combined with ReLU)
-    hot = (b > 0.0).astype(x.dtype)
-    # position of each non-zero inside the compacted stream
-    pos = jnp.cumsum(hot, axis=-1) - 1.0
-    tgt = jax.lax.broadcasted_iota(x.dtype, (rows, cols // bank, bank, bank), 3)
-    # perm[i, j] = 1 iff element i is the j-th non-zero of its bank
-    perm = (pos[..., None] == tgt) * hot[..., None]
-    vals = jnp.einsum("rbi,rbij->rbj", b, perm, preferred_element_type=x.dtype)
-    vals_ref[...] = vals.reshape(rows, cols)
-    hot_ref[...] = hot.reshape(rows, cols)
+    x = jnp.maximum(x_ref[...].astype(jnp.float32), 0.0)  # fused ReLU (paper:
+    rows, cols = x.shape                                  # encode is combined
+    hot = (x > 0.0).astype(jnp.float32)                   # with ReLU)
+    upper, same = _bank_mats(cols, bank)
+    pos = _positions(hot, upper)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) % bank
+    vals = jnp.zeros((rows, cols), jnp.float32)
+    for q in range(bank):
+        # each bank's q-th non-zero, broadcast over its bank, kept at slot q
+        s = jnp.dot(jnp.where(pos == q, x, 0.0), same,
+                    preferred_element_type=jnp.float32, precision=_HIGHEST)
+        vals = jnp.where(slot == q, s, vals)
+    vals_ref[...] = vals.astype(vals_ref.dtype)
+    hot_ref[...] = hot.astype(hot_ref.dtype)
 
 
 def _decode_kernel(vals_ref, hot_ref, out_ref, *, bank: int):
-    vals = vals_ref[...]
-    hot = hot_ref[...]
+    vals = vals_ref[...].astype(jnp.float32)
+    hot = hot_ref[...].astype(jnp.float32)
     rows, cols = vals.shape
-    v = vals.reshape(rows, cols // bank, bank)
-    h = hot.reshape(rows, cols // bank, bank)
-    pos = jnp.cumsum(h, axis=-1) - 1.0
-    tgt = jax.lax.broadcasted_iota(vals.dtype, (rows, cols // bank, bank, bank), 3)
-    perm = (pos[..., None] == tgt) * h[..., None]          # (r, b, i, j)
-    out = jnp.einsum("rbj,rbij->rbi", v, perm, preferred_element_type=vals.dtype)
-    out_ref[...] = out.reshape(rows, cols)
+    upper, same = _bank_mats(cols, bank)
+    pos = _positions(hot, upper)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) % bank
+    out = jnp.zeros((rows, cols), jnp.float32)
+    for q in range(bank):
+        # slot q of each bank, broadcast back to the element at position q
+        b = jnp.dot(jnp.where(slot == q, vals, 0.0), same,
+                    preferred_element_type=jnp.float32, precision=_HIGHEST)
+        out = jnp.where(pos == q, b, out)
+    out_ref[...] = out.astype(out_ref.dtype)
 
 
-def _grid_specs(rows: int, cols: int, n_out: int):
-    grid = (pl.cdiv(rows, ROW_TILE), pl.cdiv(cols, COL_TILE))
-    spec = pl.BlockSpec((ROW_TILE, COL_TILE), lambda r, c: (r, c))
-    return grid, spec
+def _spec(rows: int, cols: int):
+    """Grid and block: callers pad rows to the row tile (ops.rfc_encode);
+    a column count that is not a whole number of 128-lane tiles rides as
+    one full-width block."""
+    r_tile = min(ROW_TILE, rows)
+    c_tile = COL_TILE if cols % COL_TILE == 0 else cols
+    if rows % r_tile:
+        raise ValueError(f"rows={rows} not a multiple of the row tile "
+                         f"{r_tile}; pad them (ops.rfc_encode does this)")
+    grid = (rows // r_tile, cols // c_tile)
+    return grid, pl.BlockSpec((r_tile, c_tile), lambda r, c: (r, c))
 
 
 @functools.partial(jax.jit, static_argnames=("bank", "interpret"))
-def rfc_encode_pallas(x: jnp.ndarray, bank: int = BANK, interpret: bool = True):
+def rfc_encode_pallas(x: jnp.ndarray, *, bank: int = BANK, interpret: bool):
     """ReLU + bank-compact.  x: (rows, C) -> (values, hot) both (rows, C)."""
     rows, cols = x.shape
     if cols % bank:
         raise ValueError(f"C={cols} not divisible by bank={bank}")
-    grid, spec = _grid_specs(rows, cols, 2)
+    grid, spec = _spec(rows, cols)
     return pl.pallas_call(
         functools.partial(_encode_kernel, bank=bank),
         grid=grid,
@@ -80,17 +114,18 @@ def rfc_encode_pallas(x: jnp.ndarray, bank: int = BANK, interpret: bool = True):
             jax.ShapeDtypeStruct((rows, cols), x.dtype),
         ],
         interpret=interpret,
+        name="rfc_encode",
     )(x)
 
 
 @functools.partial(jax.jit, static_argnames=("bank", "interpret"))
-def rfc_decode_pallas(values: jnp.ndarray, hot: jnp.ndarray, bank: int = BANK,
-                      interpret: bool = True) -> jnp.ndarray:
-    """Bank-decompact via the transposed one-hot permutation matmul:
+def rfc_decode_pallas(values: jnp.ndarray, hot: jnp.ndarray, *,
+                      bank: int = BANK, interpret: bool) -> jnp.ndarray:
+    """Bank-decompact via the same one-hot matmuls in reverse:
     (values, hot) (rows, C) -> dense (rows, C).  Exact inverse of
     :func:`rfc_encode_pallas` on post-ReLU data."""
     rows, cols = values.shape
-    grid, spec = _grid_specs(rows, cols, 1)
+    grid, spec = _spec(rows, cols)
     return pl.pallas_call(
         functools.partial(_decode_kernel, bank=bank),
         grid=grid,
@@ -98,4 +133,5 @@ def rfc_decode_pallas(values: jnp.ndarray, hot: jnp.ndarray, bank: int = BANK,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, cols), values.dtype),
         interpret=interpret,
+        name="rfc_decode",
     )(values, hot)

@@ -49,7 +49,8 @@ def windowed_similarity_pallas(
     ring_th: jnp.ndarray,    # (S, K, Vp, Ce)
     ring_ph: jnp.ndarray,    # (S, K, Vp, Ce)
     valid: int,              # live input-joint count (columns >= it masked)
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Fused window-sum → similarity → masked softmax per slab slot:
     (S, K, Vp, Ce) rings -> (S, Vp, Vp) normalized graphs.
@@ -68,4 +69,5 @@ def windowed_similarity_pallas(
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((S, Vp, Vp), ring_th.dtype),
         interpret=interpret,
+        name="window_sim",
     )(ring_th, ring_ph)

@@ -40,6 +40,9 @@ from repro.models import registry
 from repro.optim import adamw
 from repro.train.steps import make_prefill_step, make_serve_step, make_train_step
 
+# the dry run compiles on fake host devices and projects onto v5e pods
+PROJECTED_DEVICE_KIND = "TPU v5 lite"
+
 
 def _rules_for(cfg: ModelConfig, shape_name: str, mesh) -> Dict:
     rules = dict(shd.DEFAULT_RULES)
@@ -200,6 +203,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     hlo = compiled.as_text()
     hc = analyze_hlo(hlo)   # trip-count-aware static analysis (per chip)
     terms = roofline_terms(hc, model_flops(cfg, shape_name), chips,
+                           PROJECTED_DEVICE_KIND,
                            model_bytes_total=model_bytes(cfg, shape_name))
     if dump_hlo:
         (out_dir / f"{cell_id}.hlo").write_text(hlo)

@@ -16,7 +16,25 @@ def make_smoke_mesh():
     return jax.make_mesh((1, 1), ("data", "model"))
 
 
-# TPU v5e-class hardware constants for the roofline model (per chip).
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-HBM_BW = 819e9                    # B/s
-ICI_BW = 50e9                     # B/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 394 TOP/s int8,
+# 819 GB/s HBM, 1,600 Gbit/s of interconnect over four links).
+PEAKS = {
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,     # FLOP/s
+        "ops_int8": 394e12,       # OP/s
+        "hbm_bw": 819e9,          # B/s
+        "ici_bw": 50e9,           # B/s per link
+    },
+}
+
+
+def peak_rates(device_kind: str) -> dict:
+    """The published peaks of one chip of ``device_kind``.  A kind missing
+    from :data:`PEAKS` raises: no device borrows another's numbers."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})") from None

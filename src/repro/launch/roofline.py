@@ -12,18 +12,21 @@ from __future__ import annotations
 
 from typing import Dict
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import peak_rates
 
 
 def roofline_terms(
     hlo_cost: Dict,
     model_flops_total: float,
     chips: int,
+    device_kind: str,
     model_bytes_total: float = 0.0,
-    peak_flops: float = PEAK_FLOPS_BF16,
-    hbm_bw: float = HBM_BW,
-    ici_bw: float = ICI_BW,
 ) -> Dict:
+    """Roofline terms of one compiled step against the published peaks of
+    ``device_kind`` (:func:`repro.launch.mesh.peak_rates`)."""
+    peaks = peak_rates(device_kind)
+    peak_flops, hbm_bw, ici_bw = (peaks["flops_bf16"], peaks["hbm_bw"],
+                                  peaks["ici_bw"])
     flops = float(hlo_cost.get("flops", 0.0))
     hbm_bytes = float(hlo_cost.get("bytes", 0.0))
     coll = float(hlo_cost.get("collective_bytes", 0.0))

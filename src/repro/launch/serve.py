@@ -24,7 +24,8 @@ Modes:
               one pre-built slab per tier, hysteresis grow/shrink,
               session migration via snapshot/restore).  ``--mesh N``
               shards the slab tick over an N-device 1-D batch mesh (on
-              CPU the fake-device flag is set automatically);
+              a ``JAX_PLATFORMS=cpu`` run the fake-device flag is set
+              automatically);
               ``--replicas R`` additionally serves the load through a
               :class:`repro.distributed.router.ReplicaRouter` over R
               service replicas with periodic drain-and-rebalance.
@@ -331,13 +332,14 @@ def _parse_tiers(spec: str):
 
 
 def _ensure_fake_devices(n: int) -> None:
-    """Make at least ``n`` host devices visible for ``--mesh n``.
+    """On a CPU run (``JAX_PLATFORMS=cpu``), make at least ``n`` host
+    devices visible for ``--mesh n``; a run on chips uses the real ones.
 
     Must run before jax's backend initializes (the flag is read once);
     a user-provided ``--xla_force_host_platform_device_count`` wins.  If
     the platform still comes up short, ``make_batch_mesh`` raises with
     the same flag in the message."""
-    if n <= 1:
+    if n <= 1 or os.environ.get("JAX_PLATFORMS", "").split(",") != ["cpu"]:
         return
     flags = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" in flags:
@@ -430,8 +432,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "frame (default: SloConfig default)")
     p.add_argument("--mesh", type=int, default=0,
                    help="shard the slab tick over an N-device 1-D batch "
-                        "mesh (0/1 -> single device; on CPU the "
-                        "fake-device XLA flag is set automatically)")
+                        "mesh (0/1 -> single device; with "
+                        "JAX_PLATFORMS=cpu the fake-device XLA flag is "
+                        "set automatically)")
     p.add_argument("--replicas", type=int, default=1,
                    help="also serve the load through a ReplicaRouter over "
                         "R service replicas (adds the routed BENCH row)")
@@ -563,6 +566,9 @@ def _print_sessions(results) -> None:
 def main(argv=None):
     """CLI entry: subcommand form, with the legacy flag form mapped."""
     from repro.core.agcn.engine import BACKENDS
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
 
     argv = list(sys.argv[1:] if argv is None else argv)
     legacy = False

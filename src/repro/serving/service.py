@@ -176,9 +176,12 @@ class GcnService:
                          BN stats and ring rows replicated) and every
                          jitted entry point is compiled with matching
                          output shardings, so one service tick runs
-                         SPMD across the mesh devices.  Every capacity
-                         tier must divide the mesh size.  None (default)
-                         = single-device service, unchanged.
+                         SPMD across the mesh devices.  Plans and BN
+                         stats are committed to the mesh, replicated, so
+                         a one-device mesh pins the whole service to that
+                         device (the router's replica placement).  Every
+                         capacity tier must divide the mesh size.  None
+                         (default) = single-device service, unchanged.
       retain_records   — bound on per-session host bookkeeping: only the
                          most recent ``retain_records`` finished/missed
                          sessions keep their request/record entries
@@ -374,6 +377,13 @@ class GcnService:
                 S: tuple(jax.device_put(s, sh) for s, sh in
                          zip(slabs, self._slab_shardings))
                 for S, slabs in self._tier_slabs.items()}
+            # plans and BN stats ride every dispatch: commit them to the
+            # mesh too (replicated), so no call re-uploads them and a
+            # one-device mesh pins the whole service to its device
+            self._topo_plans = jax.device_put(self._topo_plans, rep)
+            self._topo_stats = jax.device_put(self._topo_stats, rep)
+            self.plans = self._topo_plans[self.primary]
+            self.bn_stats = self._topo_stats[self.primary]
         # the *live* slab is a deep copy, never an alias of a tier entry:
         # the fused tick donates its slab argument (XLA reuses the buffers
         # in place and deletes them Python-side), and a donated alias

@@ -1,6 +1,7 @@
 #!/usr/bin/env bash
 # Tier-1 verify: the exact pytest line CI and the PR driver run.
-# CPU-only container: pin the platform so jax never probes for TPU.
+# Tests run on the CPU: pin the platform so jax never probes for a TPU;
+# Pallas kernels then run in interpret mode, derived from the platform.
 #
 # Tiers:
 #   ./test.sh           full tier — whole suite (slow cells included) plus a

@@ -13,7 +13,7 @@ from repro.kernels import ops, ref
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_rfc_encode_matches_ref(rows, cols, dtype):
     x = jax.random.normal(jax.random.PRNGKey(rows * cols), (rows, cols), dtype)
-    v_k, h_k = ops.rfc_encode(x)
+    v_k, h_k = ops.rfc_encode(x, interpret=ops.interpret_mode())
     v_r, h_r = ref.rfc_encode_ref(x.astype(jnp.float32))
     np.testing.assert_allclose(
         np.asarray(v_k, np.float32), np.asarray(v_r), atol=1e-2)
@@ -23,16 +23,16 @@ def test_rfc_encode_matches_ref(rows, cols, dtype):
 @pytest.mark.parametrize("rows,cols", [(8, 16), (32, 64), (100, 48)])
 def test_rfc_roundtrip(rows, cols):
     x = jax.random.normal(jax.random.PRNGKey(1), (rows, cols))
-    v, h = ops.rfc_encode(x)
-    out = ops.rfc_decode(v, h)
+    v, h = ops.rfc_encode(x, interpret=ops.interpret_mode())
+    out = ops.rfc_decode(v, h, interpret=ops.interpret_mode())
     np.testing.assert_allclose(np.asarray(out), np.maximum(np.asarray(x), 0),
                                atol=1e-6)
 
 
 def test_rfc_multidim():
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 5, 64))
-    v, h = ops.rfc_encode(x)
-    out = ops.rfc_decode(v, h)
+    v, h = ops.rfc_encode(x, interpret=ops.interpret_mode())
+    out = ops.rfc_decode(v, h, interpret=ops.interpret_mode())
     assert out.shape == x.shape
     np.testing.assert_allclose(np.asarray(out), np.maximum(np.asarray(x), 0),
                                atol=1e-6)
@@ -51,7 +51,7 @@ def test_cavity_tconv_matches_ref(pattern, F, C, T, stride):
     out_ref = ref.cavity_tconv_ref(x, jnp.asarray(wm), stride=stride)
     wp, taps, inv = ops.pack_cavity_weights(wm, mask)
     out = ops.cavity_tconv(x, jnp.asarray(wp), jnp.asarray(taps), inv, F,
-                           stride=stride)
+                           stride=stride, interpret=ops.interpret_mode())
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                atol=1e-4, rtol=1e-4)
 
@@ -67,7 +67,7 @@ def test_graph_sconv_matches_ref(R, V, Ci, Co, K):
     x = jax.random.normal(k, (2, R // 2, V, Ci))
     g = jax.random.normal(k, (K, V, V))
     w = jax.random.normal(k, (K, Ci, Co))
-    out = ops.graph_sconv(x, g, w)
+    out = ops.graph_sconv(x, g, w, interpret=ops.interpret_mode())
     expected = ref.graph_sconv_ref(x.reshape(R, V, Ci), g, w).reshape(
         2, R // 2, V, Co)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
@@ -85,7 +85,8 @@ def test_flash_decode_matches_ref(B, S, Hkv, G, D, valid):
     q = jax.random.normal(ks[0], (B, Hkv, G, D))
     k = jax.random.normal(ks[1], (B, S, Hkv, D))
     v = jax.random.normal(ks[2], (B, S, Hkv, D))
-    out = flash_decode_pallas(q, k, v, jnp.asarray(valid, jnp.int32))
+    out = flash_decode_pallas(q, k, v, jnp.asarray(valid, jnp.int32),
+                              interpret=ops.interpret_mode())
     expected = ref.flash_decode_ref(q, k, v, valid)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                atol=3e-5, rtol=3e-5)
