@@ -1,0 +1,6 @@
+"""Share of the traced window with no op on the device (backlog)."""
+from benchlib import readers
+
+
+def read(ctx):
+    return readers.idle_share(ctx)

@@ -1,0 +1,6 @@
+"""Roofline share of the sconv kernel family (backlog traffic)."""
+from benchlib import readers
+
+
+def read(ctx):
+    return readers.roofline(ctx, "sconv")

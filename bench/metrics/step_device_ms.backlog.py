@@ -1,0 +1,12 @@
+"""Device-busy time of one slab-step program execution, from the trace."""
+from benchlib import readers
+
+
+def read(ctx):
+    return readers.step_device_ms(ctx)
+"""Device-busy time of one (backlog) program execution, from the trace."""
+from benchlib import readers
+
+
+def read(ctx):
+    return readers.step_device_ms(ctx)

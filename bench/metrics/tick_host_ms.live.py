@@ -1,0 +1,6 @@
+"""Host time inside GcnService.tick() per tick (live traffic)."""
+from benchlib import readers
+
+
+def read(ctx):
+    return readers.tick_host_ms(ctx)

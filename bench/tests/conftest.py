@@ -1,0 +1,7 @@
+"""The benchmark's own tests: ``python -m pytest bench/tests`` on the CPU."""
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [BENCH, os.path.join(os.path.dirname(BENCH), "src")]
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
