@@ -1,0 +1,7 @@
+"""Host time per tick in the jitted calls (svc.dispatch), from the
+program's spans (backlog traffic)."""
+from benchlib import phases
+
+
+def read(ctx):
+    return phases.phase_ms(ctx, "dispatch")

@@ -1,0 +1,7 @@
+"""Host time per tick in drain bookkeeping (svc.drain), from the program's
+spans (live traffic)."""
+from benchlib import phases
+
+
+def read(ctx):
+    return phases.phase_ms(ctx, "drain")

@@ -53,6 +53,32 @@ from repro.serving.slo import CONTROL_POLICIES, SloConfig, SloController
 SESSION_STATES = ("queued", "active", "draining", "done", "missed",
                   "rejected")
 
+# the serving tick's named phases, in the order tick() runs them; readback
+# is also reached from poll(wait=True) and metrics()
+TICK_PHASES = ("feed", "stage", "dispatch", "readback", "drain")
+HOST_PHASES = ("feed", "stage", "dispatch", "drain")
+
+
+class _Phase:
+    """One named phase of the serving tick: a profiler span ``svc.<name>``
+    around the block (on the device trace's clock when a profiler runs)
+    and the block's ``time.monotonic()`` duration added to
+    ``phase_s[name]``.  Entering returns the phase's start time."""
+
+    __slots__ = ("phase_s", "name", "span", "t0")
+
+    def __init__(self, phase_s: Dict[str, float], name: str, span):
+        self.phase_s, self.name, self.span = phase_s, name, span
+
+    def __enter__(self) -> float:
+        self.span.__enter__()
+        self.t0 = time.monotonic()
+        return self.t0
+
+    def __exit__(self, *exc) -> None:
+        self.phase_s[self.name] += time.monotonic() - self.t0
+        self.span.__exit__(*exc)
+
 
 @dataclasses.dataclass(frozen=True)
 class SessionHandle:
@@ -497,8 +523,10 @@ class GcnService:
         self._retired: deque = deque()
         self._tick = 0
         self._last_logits: Optional[Any] = None   # device array until forced
-        self.wall_host_s = 0.0                # host scheduling inside tick()
-        self.wall_device_s = 0.0              # forced-readback device waits
+        # seconds spent in each named phase (TICK_PHASES); wall_host_s and
+        # wall_device_s are sums of these
+        self.phase_s: Dict[str, float] = dict.fromkeys(TICK_PHASES, 0.0)
+        self._span = jax.profiler.TraceAnnotation   # phase span factory
         self.device_dispatches = 0            # jitted calls issued by tick()
         self.tier_ticks: Dict[int, int] = {S: 0 for S in tiers}
 
@@ -629,12 +657,30 @@ class GcnService:
         return self._tick
 
     @property
+    def wall_host_s(self) -> float:
+        """Host time inside ``tick()`` less its readback waits: the sum of
+        the host phases ``feed + stage + dispatch + drain`` of
+        ``phase_s``."""
+        return sum(self.phase_s[p] for p in HOST_PHASES)
+
+    @property
+    def wall_device_s(self) -> float:
+        """Host time blocked on forced logit readback
+        (``phase_s["readback"]``), from ``tick()``, ``poll(wait=True)``
+        or ``metrics()``.  It is not device time: the device's own time
+        is read from a profiler trace."""
+        return self.phase_s["readback"]
+
+    @property
     def wall_s(self) -> float:
-        """Total serving time inside ``tick()``: host scheduling
-        (``wall_host_s``) plus forced-readback device waits
-        (``wall_device_s``) — kept as a property for back-compat with the
-        old single counter."""
+        """Total serving time: ``wall_host_s`` plus ``wall_device_s``, so
+        the sum of every named phase — kept as a property for back-compat
+        with the old single counter."""
         return self.wall_host_s + self.wall_device_s
+
+    def _phase(self, name: str) -> _Phase:
+        """The context manager of one named phase (see ``TICK_PHASES``)."""
+        return _Phase(self.phase_s, name, self._span("svc." + name))
 
     @property
     def capacity(self) -> int:
@@ -743,7 +789,7 @@ class GcnService:
         tick whose async readback is still pending — so a client polling
         every tick costs no device sync (the fused path's readback
         overlap survives the polling).  ``wait=True`` forces the pending
-        readback first (the wait is timed into ``wall_device_s``),
+        readback first (the wait is the ``readback`` phase),
         guaranteeing the logits reflect the latest tick."""
         req = self._req(h)
         rec = self._records.get(h.sid)
@@ -845,14 +891,13 @@ class GcnService:
 
         The fused tick keeps ``_last_logits`` as a device array — a
         future the host only waits on when someone actually reads it
-        (``poll``, a finishing session, ``metrics``).  The block is timed
-        into ``wall_device_s``: this is the forced-readback point that
-        separates device time from host scheduling time."""
+        (``poll``, a finishing session, ``metrics``).  The block is the
+        ``readback`` phase (``wall_device_s``): the host waiting on the
+        device, kept apart from host scheduling time."""
         if (self._last_logits is not None
                 and not isinstance(self._last_logits, np.ndarray)):
-            t0 = time.monotonic()
-            self._last_logits = np.asarray(self._last_logits)
-            self.wall_device_s += time.monotonic() - t0
+            with self._phase("readback"):
+                self._last_logits = np.asarray(self._last_logits)
         return self._last_logits
 
     def _topology_groups(self) -> List[Tuple[str, np.ndarray]]:
@@ -874,20 +919,19 @@ class GcnService:
                 if masks[t].any()]
         return out
 
-    def _step_groups(self, tp, groups, logits):
+    def _step_groups(self, frames, groups, logits):
         """Step each non-primary skeleton group: one plain dispatch per
         group with that topology's plans and BN stats over the shared
         slab, everything outside the group held (held slots keep their
-        state bit-for-bit and report their running prediction).  Returns
-        the last dispatch's logits — it covers the whole slab, because
-        held rows are recomputed from the post-step pool and the fc head
-        is identical across topology plans by construction."""
-        jnp = self._jnp
-        for t, m in groups:
+        state bit-for-bit and report their running prediction).
+        ``groups`` holds each group's staged ``(topology, valid, reset,
+        hold)``.  Returns the last dispatch's logits — it covers the whole
+        slab, because held rows are recomputed from the post-step pool and
+        the fc head is identical across topology plans by construction."""
+        for t, valid, reset, hold in groups:
             self.slabs, logits = self._step(
-                self._topo_plans[t], self.slabs, jnp.asarray(tp.frames),
-                jnp.asarray(tp.valid & m), jnp.asarray(tp.reset & m),
-                jnp.asarray(tp.hold | ~m), stats=self._topo_stats[t])
+                self._topo_plans[t], self.slabs, frames, valid, reset, hold,
+                stats=self._topo_stats[t])
             self.device_dispatches += 1
         return logits
 
@@ -904,151 +948,165 @@ class GcnService:
         dispatch and immediately resumes scheduling — the transfer is
         only forced when a session finishes this tick, someone polls, or
         metrics are read, so tick *t*'s device work overlaps tick
-        *t+1*'s host-side planning."""
+        *t+1*'s host-side planning.
+
+        The tick runs as contiguous named phases (``TICK_PHASES``), each
+        a ``svc.<phase>`` profiler span timed into ``phase_s``: *feed*
+        (controllers, ``tick_inputs``, outcome and group bookkeeping),
+        *stage* (host→device inputs), *dispatch* (every jitted call),
+        *readback* (a forced readback, when one is due) and *drain*
+        (``tick_outputs``, record retirement)."""
         jnp = self._jnp
-        t0 = time.monotonic()
-        dev0 = self.wall_device_s
-        if self.capman is not None or self.slo is not None:
-            # sweep deadline-expired sessions *before* the controller
-            # looks: expired slots/queue entries are not demand,
-            # and counting them used to trigger spurious grows
-            self.sched.sweep_expired(self._tick)
-        if self.slo is not None:
-            # the leading-edge breach signal: the oldest queued session's
-            # wait so far — a saturated queue never latches first logits,
-            # so the p99 window alone would look healthy while everyone
-            # starves
-            queue_age = max(
-                (self._tick - AdmissionQueue._req(it).arrival
-                 for it in self.sched.queue), default=0)
-            # the in-flight twin: an admitted-but-unlatched session's
-            # first logit cannot land before admission + pipeline delay,
-            # so its committed latency is already known — without it, a
-            # recovery streak could un-shed while the slab is still full
-            # of sessions guaranteed to breach when they latch
-            inflight_age = max(
-                (slot.admitted + self.sched.first_logit_delay - 1
-                 - slot.req.arrival
-                 for slot in self.sched.slots
-                 if slot is not None and slot.first_logit_tick < 0),
-                default=0)
-            target = self.slo.observe(
-                self.sched.busy(), len(self.sched.queue), self._tick,
-                queue_age=queue_age, inflight_age=inflight_age)
-            if target is not None and target != self.capacity:
-                self._migrate(target)
-        elif self.capman is not None:
-            target = self.capman.observe(
-                self.sched.busy(), len(self.sched.queue), self._tick)
-            if target is not None:
-                self._migrate(target)
-        tp = self.sched.tick_inputs(self._tick, t0)
-        outcome = None
-        if self.record_outcomes:
-            # pure host ints, no wall times / logits: the per-tick shape
-            # the golden replay tests lock byte-for-byte.  Captured right
-            # after tick_inputs (a tiny degraded session can finish on
-            # its own admission tick, freeing the slot before outputs).
-            outcome = {
-                "tick": self._tick,
-                "capacity": self.capacity,
-                "busy": self.sched.busy(),
-                "queued": len(self.sched.queue),
-                "admitted": sorted(
-                    self.sched.slots[s].req.sid
-                    for s in np.flatnonzero(tp.reset)
-                    if self.sched.slots[s] is not None),
-                "restored": sorted(sid for _, sid in tp.restore),
-                "preempted": sorted(sid for _, sid in tp.snapshot),
-                "held": int(tp.hold.sum()),
-                "shed": self._shed_tick,
-            }
-            self._shed_tick = []
-        # mixed-skeleton slab: partition the slots by topology.  The
-        # primary group carries the events and the free slots; every
-        # other group is stepped by its own plans afterwards.  Group
-        # masks: valid/reset only inside the group (reset must be
-        # group-masked — step_frames resets *before* the hold select),
-        # hold everything outside it.  None = single-topology service,
-        # which takes exactly the legacy dispatch.
-        groups = (self._topology_groups()
-                  if len(self.topologies) > 1 else None)
-        valid, reset, hold = tp.valid, tp.reset, tp.hold
-        if groups is not None:
-            mp = groups[0][1]
-            valid, reset, hold = valid & mp, reset & mp, hold | ~mp
-        if self.fused:
-            if tp.snapshot or tp.restore:
+        with self._phase("feed") as t0:
+            if self.capman is not None or self.slo is not None:
+                # sweep deadline-expired sessions *before* the controller
+                # looks: expired slots/queue entries are not demand,
+                # and counting them used to trigger spurious grows
+                self.sched.sweep_expired(self._tick)
+            if self.slo is not None:
+                # the leading-edge breach signal: the oldest queued
+                # session's wait so far — a saturated queue never latches
+                # first logits, so the p99 window alone would look healthy
+                # while everyone starves
+                queue_age = max(
+                    (self._tick - AdmissionQueue._req(it).arrival
+                     for it in self.sched.queue), default=0)
+                # the in-flight twin: an admitted-but-unlatched session's
+                # first logit cannot land before admission + pipeline
+                # delay, so its committed latency is already known —
+                # without it, a recovery streak could un-shed while the
+                # slab is still full of sessions guaranteed to breach when
+                # they latch
+                inflight_age = max(
+                    (slot.admitted + self.sched.first_logit_delay - 1
+                     - slot.req.arrival
+                     for slot in self.sched.slots
+                     if slot is not None and slot.first_logit_tick < 0),
+                    default=0)
+                target = self.slo.observe(
+                    self.sched.busy(), len(self.sched.queue), self._tick,
+                    queue_age=queue_age, inflight_age=inflight_age)
+                if target is not None and target != self.capacity:
+                    self._migrate(target)
+            elif self.capman is not None:
+                target = self.capman.observe(
+                    self.sched.busy(), len(self.sched.queue), self._tick)
+                if target is not None:
+                    self._migrate(target)
+            tp = self.sched.tick_inputs(self._tick, t0)
+            outcome = None
+            if self.record_outcomes:
+                # pure host ints, no wall times / logits: the per-tick
+                # shape the golden replay tests lock byte-for-byte.
+                # Captured right after tick_inputs (a tiny degraded
+                # session can finish on its own admission tick, freeing
+                # the slot before outputs).
+                outcome = {
+                    "tick": self._tick,
+                    "capacity": self.capacity,
+                    "busy": self.sched.busy(),
+                    "queued": len(self.sched.queue),
+                    "admitted": sorted(
+                        self.sched.slots[s].req.sid
+                        for s in np.flatnonzero(tp.reset)
+                        if self.sched.slots[s] is not None),
+                    "restored": sorted(sid for _, sid in tp.restore),
+                    "preempted": sorted(sid for _, sid in tp.snapshot),
+                    "held": int(tp.hold.sum()),
+                    "shed": self._shed_tick,
+                }
+                self._shed_tick = []
+            # mixed-skeleton slab: partition the slots by topology.  The
+            # primary group carries the events and the free slots; every
+            # other group is stepped by its own plans afterwards.  Group
+            # masks: valid/reset only inside the group (reset must be
+            # group-masked — step_frames resets *before* the hold
+            # select), hold everything outside it.  None =
+            # single-topology service, which takes exactly the legacy
+            # dispatch.
+            groups = (self._topology_groups()
+                      if len(self.topologies) > 1 else None)
+            valid, reset, hold = tp.valid, tp.reset, tp.hold
+            if groups is not None:
+                mp = groups[0][1]
+                valid, reset, hold = valid & mp, reset & mp, hold | ~mp
+            # a session finishing this tick needs its logits row before
+            # drain accounting, so the readback is forced then; the legacy
+            # tick is synchronous and always forces it.  Otherwise the
+            # fused path leaves the future pending.
+            force = not self.fused or any(
+                slot is not None and not slot.held
+                and slot.total is not None and slot.rel == slot.total - 1
+                for slot in self.sched.slots)
+        with self._phase("stage"):
+            events = self.fused and bool(tp.snapshot or tp.restore)
+            snap_at, rest_at = (), ()
+            frames = jnp.asarray(tp.frames)
+            masks = (jnp.asarray(valid), jnp.asarray(reset),
+                     jnp.asarray(hold))
+            if events:
+                orders = (jnp.asarray(tp.snap_order),
+                          jnp.asarray(tp.rest_order))
+            elif not self.fused:
+                snap_at = [(jnp.asarray(s), sid) for s, sid in tp.snapshot]
+                rest_at = [(jnp.asarray(s), sid) for s, sid in tp.restore]
+            group_args = [(t, jnp.asarray(tp.valid & m),
+                           jnp.asarray(tp.reset & m),
+                           jnp.asarray(tp.hold | ~m))
+                          for t, m in (groups or [])[1:]]
+        with self._phase("dispatch"):
+            if events:
                 # event tick — one donated dispatch: snapshot gathers ->
                 # restore scatters -> reset/hold-masked slab step, all
                 # inside _fused_tick.  self.slabs/self._rings die at this
                 # call (donated) and are rebound to the outputs — never
                 # re-read the old references.
                 self.slabs, logits, self._rings = self._fused_tick(
-                    self.plans, self.slabs, jnp.asarray(tp.frames),
-                    jnp.asarray(valid), jnp.asarray(reset),
-                    jnp.asarray(hold), jnp.asarray(tp.snap_order),
-                    jnp.asarray(tp.rest_order), self._rings)
+                    self.plans, self.slabs, frames, *masks, *orders,
+                    self._rings)
             else:
-                # no-event tick (the common case): the plain slab step is
-                # the same single dispatch minus the ring plumbing — the
-                # fused win here is skipping the per-tick readback, not
-                # the kernel shape
-                self.slabs, logits = self._step(
-                    self.plans, self.slabs, jnp.asarray(tp.frames),
-                    jnp.asarray(valid), jnp.asarray(reset),
-                    jnp.asarray(hold))
+                # legacy events: capture before restore/step
+                for s, sid in snap_at:
+                    self._snaps[sid] = tuple(self._snap_fn(slab, s)
+                                             for slab in self.slabs)
+                    self.device_dispatches += len(self.slabs)
+                for s, sid in rest_at:
+                    snaps = self._snaps.pop(sid)
+                    self.slabs = tuple(
+                        self._rest_fn(slab, s, sn)
+                        for slab, sn in zip(self.slabs, snaps))
+                    self.device_dispatches += len(self.slabs)
+                # no-event fused tick (the common case): the plain slab
+                # step is the same single dispatch minus the ring
+                # plumbing — the fused win here is skipping the per-tick
+                # readback, not the kernel shape
+                self.slabs, logits = self._step(self.plans, self.slabs,
+                                                frames, *masks)
             self.device_dispatches += 1
-            if groups is not None:
-                logits = self._step_groups(tp, groups[1:], logits)
+            if group_args:
+                logits = self._step_groups(frames, group_args, logits)
             self._last_logits = logits           # device array; forced lazily
-            # a session finishing this tick needs its logits row now —
-            # force the readback (timed as device wait) before drain
-            # accounting; otherwise leave the future pending
-            if any(slot is not None and not slot.held
-                   and slot.total is not None and slot.rel == slot.total - 1
-                   for slot in self.sched.slots):
-                self._force_logits()
-        else:
-            for s, sid in tp.snapshot:      # capture before restore/step
-                self._snaps[sid] = tuple(
-                    self._snap_fn(slab, jnp.asarray(s))
-                    for slab in self.slabs)
-                self.device_dispatches += len(self.slabs)
-            for s, sid in tp.restore:
-                snaps = self._snaps.pop(sid)
-                self.slabs = tuple(
-                    self._rest_fn(slab, jnp.asarray(s), sn)
-                    for slab, sn in zip(self.slabs, snaps))
-                self.device_dispatches += len(self.slabs)
-            self.slabs, logits = self._step(
-                self.plans, self.slabs, jnp.asarray(tp.frames),
-                jnp.asarray(valid), jnp.asarray(reset),
-                jnp.asarray(hold))
-            self.device_dispatches += 1
-            if groups is not None:
-                logits = self._step_groups(tp, groups[1:], logits)
-            self._last_logits = logits
-            self._force_logits()                 # legacy: synchronous tick
-        done = self.sched.tick_outputs(self._tick, self._last_logits,
-                                       time.monotonic())
-        for rec in done:
-            self._records[rec.sid] = rec
-            # the record holds the outcome; drop the frame payload so a
-            # long-lived service doesn't pin every served clip in memory
-            self._sessions[rec.sid].release_frames()
-            self._retire(rec.sid)
-        # (deadline misses release + retire through the scheduler's
-        # on_miss hook the moment they are swept)
-        if outcome is not None:
-            outcome["finished"] = sorted(r.sid for r in done)
-            outcome["missed"] = sorted(self._missed_tick)
-            self._missed_tick = []
-            self.outcomes.append(outcome)
-        self.tier_ticks[self.capacity] += 1
-        self._tick += 1
-        self.wall_host_s += ((time.monotonic() - t0)
-                             - (self.wall_device_s - dev0))
+        if force:
+            self._force_logits()
+        with self._phase("drain") as now:
+            done = self.sched.tick_outputs(self._tick, self._last_logits,
+                                           now)
+            for rec in done:
+                self._records[rec.sid] = rec
+                # the record holds the outcome; drop the frame payload so
+                # a long-lived service doesn't pin every served clip in
+                # memory
+                self._sessions[rec.sid].release_frames()
+                self._retire(rec.sid)
+            # (deadline misses release + retire through the scheduler's
+            # on_miss hook the moment they are swept)
+            if outcome is not None:
+                outcome["finished"] = sorted(r.sid for r in done)
+                outcome["missed"] = sorted(self._missed_tick)
+                self._missed_tick = []
+                self.outcomes.append(outcome)
+            self.tier_ticks[self.capacity] += 1
+            self._tick += 1
         return done
 
     def run_until_idle(self, max_ticks: int = 100_000) -> int:
