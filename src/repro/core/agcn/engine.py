@@ -120,6 +120,91 @@ class ExecutionPlan:
         return cls(arrays=children[0], static=static)
 
 
+# leaf offsets inside a packed buffer are multiples of this many elements,
+# so every unpacked leaf starts on a whole tile of the flat buffer
+PACK_ALIGN = 1024
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class ConstantLayout:
+    """Hashable layout of a pytree packed into one flat buffer per dtype —
+    the jit-cache key of a :class:`PackedConstants`, as :class:`PlanStatic`
+    is of an ExecutionPlan: the tree's structure (plan statics included)
+    and each leaf's ``(buffer, offset, shape)`` in flattening order.  The
+    hash is computed once: a jitted call hashes its arguments' aux data on
+    every dispatch."""
+
+    treedef: Any
+    leaves: Tuple[Tuple[int, int, Tuple[int, ...]], ...]
+
+    def __post_init__(self):
+        object.__setattr__(self, "_hash", hash((self.treedef, self.leaves)))
+
+    def __hash__(self) -> int:
+        return self._hash
+
+    def __eq__(self, other) -> bool:
+        return self is other or (
+            isinstance(other, ConstantLayout) and self._hash == other._hash
+            and (self.treedef, self.leaves) == (other.treedef, other.leaves))
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class PackedConstants:
+    """A pytree of constant arrays (the serving tick's ExecutionPlans and
+    frozen BN statistics) held as one flat device buffer per dtype.
+
+    A jitted call pays host time for every array it takes, so the service
+    passes its constants packed: ``buffers`` are the jit leaves, and
+    :meth:`unpack` rebuilds the original tree with static slices and
+    reshapes — inside the trace, where they cost no host time.  Packing
+    keeps every leaf's dtype and bits."""
+
+    buffers: Tuple[Any, ...]
+    layout: ConstantLayout
+
+    def tree_flatten(self):
+        """Pytree split: the buffers are leaves, the layout is static aux."""
+        return (self.buffers,), self.layout
+
+    @classmethod
+    def tree_unflatten(cls, layout, children):
+        """Rebuild from (aux, leaves) — the jax pytree protocol inverse."""
+        return cls(buffers=tuple(children[0]), layout=layout)
+
+    def unpack(self) -> Any:
+        """The packed tree, leaf for leaf, bit for bit."""
+        lay = self.layout
+        return jax.tree_util.tree_unflatten(lay.treedef, [
+            jax.lax.slice(self.buffers[b], (off,),
+                          (off + int(np.prod(shape)),)).reshape(shape)
+            for b, off, shape in lay.leaves])
+
+
+def pack_constants(tree: Any) -> PackedConstants:
+    """Pack ``tree``'s arrays into one flat device buffer per dtype, in
+    order of dtype name, each leaf at an offset aligned to
+    :data:`PACK_ALIGN` elements (host-side and once: the arrays are read
+    back to assemble the buffers)."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    host = [np.asarray(x) for x in leaves]
+    kinds = {x.dtype.name: x.dtype for x in host}
+    dtypes = tuple(sorted(kinds))
+    ends = dict.fromkeys(dtypes, 0)
+    table = []
+    for x in host:
+        d = x.dtype.name
+        table.append((dtypes.index(d), ends[d], tuple(x.shape)))
+        ends[d] += -(-x.size // PACK_ALIGN) * PACK_ALIGN
+    bufs = [np.zeros(ends[d], kinds[d]) for d in dtypes]
+    for x, (b, off, _) in zip(host, table):
+        bufs[b][off:off + x.size] = x.ravel()
+    return PackedConstants(
+        buffers=tuple(jnp.asarray(b) for b in bufs),
+        layout=ConstantLayout(treedef, tuple(table)))
+
+
 # ---------------------------------------------------------------------------
 # shared math (used by both backends and by the legacy-compatible paths)
 # ---------------------------------------------------------------------------
@@ -804,7 +889,8 @@ class StreamState:
     migration carry them for free.  ``t_raw``
     (S,) counts raw frames per slot; ``pool_*`` hold the per-slot running
     temporal logit pool; ``bn_stats`` the frozen calibration (shared by all
-    slots — calibrated once per plan, untouched by slot resets); ``rfc``
+    slots — calibrated once per plan, untouched by slot resets; empty in a
+    bare state, whose steps take it as an override); ``rfc``
     the per-slot running RFC-encoded inter-block activations (pallas)."""
 
     t_raw: Any
@@ -862,7 +948,9 @@ def init_stream_state(
     pass of this plan's own backend) or precomputed ``bn_stats`` from
     :func:`collect_bn_stats`.  The statistics are plan-level (shared by
     every slot), so one calibration serves sessions admitted at any later
-    time."""
+    time.  ``bn_stats={}`` makes a *bare* state, per-slot leaves only, for
+    a caller that passes the statistics to every step itself (the
+    ``bn_stats`` override of :func:`step_frame`)."""
     ps = plan.static
     if bn_stats is None:
         if x_calib is None:

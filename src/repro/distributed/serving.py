@@ -78,26 +78,27 @@ def collective_cost_ms(svc, iters: int = 16) -> float:
     import jax
     import jax.numpy as jnp
 
-    from repro.train.steps import make_gcn_slab_step
+    from repro.train.steps import make_gcn_slab_step, on_packed_constants
 
     S = svc.capacity
     zf = jnp.zeros((S, svc.vmax, svc.cfg.gcn_in_channels))
     zb = jnp.zeros((S,), bool)
 
-    def timed(step, plans, slabs) -> float:
-        out = step(plans, slabs, zf, zb, zb, zb)   # compile + warm
+    def timed(step, consts, slabs) -> float:
+        out = step(consts, slabs, zf, zb, zb, zb)   # compile + warm
         jax.block_until_ready(out[1])
         t0 = time.perf_counter()
         for _ in range(iters):
-            out = step(plans, slabs, zf, zb, zb, zb)
+            out = step(consts, slabs, zf, zb, zb, zb)
         jax.block_until_ready(out[1])
         return (time.perf_counter() - t0) / iters * 1e3
 
-    sharded_ms = timed(svc._step, svc.plans, svc.slabs)
+    consts = svc._consts[svc.primary]
+    sharded_ms = timed(svc._step, consts, svc.slabs)
     dev = jax.devices()[0]
-    single = jax.jit(make_gcn_slab_step(svc.cfg))
-    plans1, slabs1 = jax.device_put((svc.plans, svc.slabs), dev)
-    single_ms = timed(single, plans1, slabs1)
+    single = jax.jit(on_packed_constants(make_gcn_slab_step(svc.cfg)))
+    consts1, slabs1 = jax.device_put((consts, svc.slabs), dev)
+    single_ms = timed(single, consts1, slabs1)
     return max(0.0, sharded_ms - single_ms)
 
 

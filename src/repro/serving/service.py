@@ -59,6 +59,13 @@ TICK_PHASES = ("feed", "stage", "dispatch", "readback", "drain")
 HOST_PHASES = ("feed", "stage", "dispatch", "drain")
 
 
+def _n_arrays(tree) -> int:
+    """The number of arrays in a pytree."""
+    import jax
+
+    return len(jax.tree_util.tree_leaves(tree))
+
+
 class _Phase:
     """One named phase of the serving tick: a profiler span ``svc.<name>``
     around the block (on the device trace's clock when a profiler runs)
@@ -249,7 +256,9 @@ class GcnService:
         from repro.core.agcn import engine
         from repro.core.agcn.graph import get_topology
         from repro.core.agcn.model import bone_stream_parents
-        from repro.train.steps import make_gcn_fused_tick, make_gcn_slab_step
+        from repro.train.steps import (make_gcn_fused_tick,
+                                       make_gcn_slab_step,
+                                       on_packed_constants)
 
         if qos not in QOS_POLICIES:
             raise ValueError(f"unknown QoS policy {qos!r}")
@@ -299,7 +308,7 @@ class GcnService:
             raise ValueError(
                 "prebuilt plans are single-topology — a multi-topology "
                 "service builds its own per-skeleton plans from cfg")
-        self._topo_plans: Dict[str, Tuple] = {}
+        topo_plans: Dict[str, Tuple] = {}
         if plans is None:
             from repro.core.pruning.plan import plan_from_config
             from repro.models import registry
@@ -312,15 +321,15 @@ class GcnService:
                 topo = self._topos[t]
                 cfg_t = dataclasses.replace(cfg, gcn_joints=topo.num_joints)
                 prune_plan = plan_from_config(cfg_t)
-                self._topo_plans[t] = tuple(
+                topo_plans[t] = tuple(
                     engine.build_execution_plan(
                         registry.init_params(cfg_t, k), cfg_t, prune_plan,
                         quant=quant, backend=backend, topology=topo,
                         pad_joints=self.vmax, sconv=sconv, csr_eps=csr_eps)
                     for k in keys)
         else:
-            self._topo_plans[self.primary] = tuple(plans)
-        self.plans = self._topo_plans[self.primary]
+            topo_plans[self.primary] = tuple(plans)
+        self.plans = topo_plans[self.primary]
         self.vmax = int(self.plans[0].static.joints)
 
         # --- frozen BN calibration (per topology, shared by every tier) ---
@@ -333,9 +342,9 @@ class GcnService:
                 "bn_stats/x_calib override a single topology's calibration "
                 "— a multi-topology service calibrates each skeleton from "
                 "its own synthetic batch")
-        self._topo_stats: Dict[str, Tuple] = {}
+        topo_stats: Dict[str, Tuple] = {}
         for t in names:
-            plans_t = self._topo_plans[t]
+            plans_t = topo_plans[t]
             topo = self._topos[t]
             transforms = [
                 lambda x: x,
@@ -357,25 +366,36 @@ class GcnService:
                 st = tuple(
                     engine.collect_bn_stats(p, tf(jnp.asarray(xc)))
                     for p, tf in zip(plans_t, transforms))
-            self._topo_stats[t] = tuple(
+            topo_stats[t] = tuple(
                 engine._pad_data_bn_stats(s, p.static)
                 for s, p in zip(st, plans_t))
-        self.bn_stats = self._topo_stats[self.primary]
+        self.bn_stats = topo_stats[self.primary]
+
+        # --- the tick's constant operands, packed -------------------------
+        # each topology's plans and frozen BN stats as one flat buffer per
+        # dtype (engine.pack_constants): every jitted tick call takes these
+        # few buffers in place of hundreds of plan and stats arrays, and
+        # unpacks them inside its trace (steps.on_packed_constants)
+        self._consts = {t: engine.pack_constants((topo_plans[t],
+                                                  topo_stats[t]))
+                        for t in names}
 
         # --- one pristine slab per capacity tier --------------------------
         # tier slabs are never mutated in place (every step/restore is a
         # functional update), so the pool entry a migration reads is always
-        # the all-zero init: entering a tier needs no reset pass
+        # the all-zero init: entering a tier needs no reset pass.  Slabs
+        # are bare — per-slot state only, empty ``bn_stats`` — since the
+        # statistics ride the packed constants
         self._tier_slabs = {
-            S: tuple(engine.init_session_slab(p, S, bn_stats=bs)
-                     for p, bs in zip(self.plans, self.bn_stats))
+            S: tuple(engine.init_session_slab(p, S, bn_stats={})
+                     for p in self.plans)
             for S in tiers}
 
         # --- mesh placement (distributed tier) ----------------------------
-        # per-slot leaves shard their leading slot axis across the 1-D
-        # mesh; plan-level BN stats (no slot axis) and snapshot-ring rows
-        # (ring axis, not slot axis) replicate.  One sharding tree per
-        # stream serves every tier — specs are shape-independent.
+        # per-slot leaves (all of a bare slab's) shard their leading slot
+        # axis across the 1-D mesh; snapshot-ring rows (ring axis, not
+        # slot axis) and the packed constants replicate.  One sharding
+        # tree per stream serves every tier — specs are shape-independent.
         self._slab_shardings = None   # per-stream StreamState of shardings
         self._ring_sharding = None    # per-stream ring pytree of shardings
         self._row_sharding = None     # (S, ...) leaves, e.g. tick logits
@@ -384,14 +404,9 @@ class GcnService:
             row = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
             rep = NamedSharding(mesh, PartitionSpec())
 
-            def _slab_sharding(slab):
-                sh = jax.tree_util.tree_map(lambda _: row, slab)
-                sh.bn_stats = jax.tree_util.tree_map(
-                    lambda _: rep, slab.bn_stats)
-                return sh
-
             self._slab_shardings = tuple(
-                _slab_sharding(s) for s in self._tier_slabs[tiers[0]])
+                jax.tree_util.tree_map(lambda _: row, s)
+                for s in self._tier_slabs[tiers[0]])
             # ring rows are slot-shaped snapshots (no slot axis) — same
             # pytree structure as ``engine.snapshot_slots``, replicated
             self._ring_sharding = tuple(
@@ -403,17 +418,17 @@ class GcnService:
                 S: tuple(jax.device_put(s, sh) for s, sh in
                          zip(slabs, self._slab_shardings))
                 for S, slabs in self._tier_slabs.items()}
-            # plans and BN stats ride every dispatch: commit them to the
-            # mesh too (replicated), so no call re-uploads them and a
-            # one-device mesh pins the whole service to its device
-            self._topo_plans = jax.device_put(self._topo_plans, rep)
-            self._topo_stats = jax.device_put(self._topo_stats, rep)
-            self.plans = self._topo_plans[self.primary]
-            self.bn_stats = self._topo_stats[self.primary]
+            # the packed constants ride every dispatch: commit them to the
+            # mesh too (replicated), so no call re-uploads them, and with
+            # them the plans and BN stats, so a one-device mesh pins the
+            # whole service to its device
+            self._consts = jax.device_put(self._consts, rep)
+            self.plans, self.bn_stats = jax.device_put(
+                (self.plans, self.bn_stats), rep)
         # the *live* slab is a deep copy, never an alias of a tier entry:
         # the fused tick donates its slab argument (XLA reuses the buffers
         # in place and deletes them Python-side), and a donated alias
-        # would destroy the pristine tier slab and the shared BN stats
+        # would destroy the pristine tier slab
         self.slabs = tuple(jax.tree_util.tree_map(jnp.copy, s)
                            for s in self._tier_slabs[tiers[0]])
 
@@ -472,17 +487,19 @@ class GcnService:
             fused_out = (self._slab_shardings, self._row_sharding,
                          self._ring_sharding)
             migrate_out = self._slab_shardings[0]
-        self._step = jax.jit(make_gcn_slab_step(cfg), out_shardings=step_out)
+        self._step = jax.jit(on_packed_constants(make_gcn_slab_step(cfg)),
+                             out_shardings=step_out)
         self._snap_fn = jax.jit(engine.snapshot_slots)
         self._rest_fn = jax.jit(engine.restore_slots)
         # the one-dispatch tick: slab and snapshot-ring pytrees are
         # DONATED (argnums 1 and 8) — XLA updates them in place and the
         # Python-side inputs die at the call; tick() must only ever pass
         # buffers it owns (self.slabs / self._rings) and immediately
-        # rebind them to the outputs
-        self._fused_tick = jax.jit(make_gcn_fused_tick(cfg),
-                                   donate_argnums=(1, 8),
-                                   out_shardings=fused_out)
+        # rebind them to the outputs.  The packed constants (argnum 0)
+        # are never donated.
+        self._fused_tick = jax.jit(
+            on_packed_constants(make_gcn_fused_tick(cfg)),
+            donate_argnums=(1, 8), out_shardings=fused_out)
         # per-stream on-device snapshot rings (fused path): ring rows are
         # slot-shaped (S-independent), so one ring serves every capacity
         # tier and rides through elastic migrations untouched
@@ -528,6 +545,16 @@ class GcnService:
         self.phase_s: Dict[str, float] = dict.fromkeys(TICK_PHASES, 0.0)
         self._span = jax.profiler.TraceAnnotation   # phase span factory
         self.device_dispatches = 0            # jitted calls issued by tick()
+        # arrays passed to and returned by tick()'s jitted calls.  Per slab
+        # step: the packed constants, both slabs, frames and three masks
+        # in; both slabs and the logits out.  The fused tick adds its two
+        # order buffers in and its snapshot rings both ways.
+        self.call_arrays = 0
+        n_slabs = _n_arrays(self.slabs)
+        self._step_arrays = {t: len(c.buffers) + 2 * n_slabs + 5
+                             for t, c in self._consts.items()}
+        self._fused_arrays = (self._step_arrays[self.primary] + 2
+                              + 2 * _n_arrays(self._rings))
         self.tier_ticks: Dict[int, int] = {S: 0 for S in tiers}
 
         if warm:
@@ -586,13 +613,13 @@ class GcnService:
             zb = jnp.zeros((S,), bool)
             # the no-event tick (fused and legacy paths alike) is the
             # plain slab step
-            _, wl = self._step(self.plans, slabs, zf, zb, zb, zb)
+            _, wl = self._step(self._consts[self.primary], slabs, zf, zb,
+                               zb, zb)
             jax.block_until_ready(wl)
-            # every non-primary skeleton group's dispatch (its own plans +
-            # BN-stats override over the same slab shape)
+            # every non-primary skeleton group's dispatch (its own packed
+            # plans and BN stats over the same slab shape)
             for t in self.topologies[1:]:
-                _, wl = self._step(self._topo_plans[t], slabs, zf, zb, zb,
-                                   zb, stats=self._topo_stats[t])
+                _, wl = self._step(self._consts[t], slabs, zf, zb, zb, zb)
                 jax.block_until_ready(wl)
             if self.fused:
                 # the fused event tick donates its slab/ring arguments, so
@@ -611,8 +638,8 @@ class GcnService:
                         jax.device_put(r, sh)
                         for r, sh in zip(wrings, self._ring_sharding))
                 zo = jnp.asarray(pad_event_orders([], max_events_for(S)))
-                out = self._fused_tick(self.plans, wslabs, zf, zb, zb, zb,
-                                       zo, zo, wrings)
+                out = self._fused_tick(self._consts[self.primary], wslabs,
+                                       zf, zb, zb, zb, zo, zo, wrings)
                 jax.block_until_ready(out[1])
         if self.qos == "preempt" and not self.fused:
             # the legacy preempt gather/scatter traces per tier shape —
@@ -631,7 +658,7 @@ class GcnService:
                 if a == b:
                     continue
                 k = min(a, b)
-                idx = jnp.arange(k, dtype=jnp.int32)
+                idx = np.arange(k, dtype=np.int32)   # as _migrate passes it
                 out = tuple(self._migrate_fn(sa, sb, idx, idx)
                             for sa, sb in zip(self._tier_slabs[a],
                                               self._tier_slabs[b]))
@@ -921,8 +948,8 @@ class GcnService:
 
     def _step_groups(self, frames, groups, logits):
         """Step each non-primary skeleton group: one plain dispatch per
-        group with that topology's plans and BN stats over the shared
-        slab, everything outside the group held (held slots keep their
+        group with that topology's packed plans and BN stats over the
+        shared slab, everything outside the group held (held slots keep their
         state bit-for-bit and report their running prediction).
         ``groups`` holds each group's staged ``(topology, valid, reset,
         hold)``.  Returns the last dispatch's logits — it covers the whole
@@ -930,9 +957,9 @@ class GcnService:
         the fc head is identical across topology plans by construction."""
         for t, valid, reset, hold in groups:
             self.slabs, logits = self._step(
-                self._topo_plans[t], self.slabs, frames, valid, reset, hold,
-                stats=self._topo_stats[t])
+                self._consts[t], self.slabs, frames, valid, reset, hold)
             self.device_dispatches += 1
+            self.call_arrays += self._step_arrays[t]
         return logits
 
     def tick(self) -> List[SessionRecord]:
@@ -1062,26 +1089,33 @@ class GcnService:
                 # call (donated) and are rebound to the outputs — never
                 # re-read the old references.
                 self.slabs, logits, self._rings = self._fused_tick(
-                    self.plans, self.slabs, frames, *masks, *orders,
-                    self._rings)
+                    self._consts[self.primary], self.slabs, frames, *masks,
+                    *orders, self._rings)
+                self.call_arrays += self._fused_arrays
             else:
                 # legacy events: capture before restore/step
                 for s, sid in snap_at:
                     self._snaps[sid] = tuple(self._snap_fn(slab, s)
                                              for slab in self.slabs)
                     self.device_dispatches += len(self.slabs)
+                    self.call_arrays += len(self.slabs) + _n_arrays(
+                        (self.slabs, self._snaps[sid]))
                 for s, sid in rest_at:
                     snaps = self._snaps.pop(sid)
+                    old = self.slabs
                     self.slabs = tuple(
                         self._rest_fn(slab, s, sn)
                         for slab, sn in zip(self.slabs, snaps))
                     self.device_dispatches += len(self.slabs)
+                    self.call_arrays += len(old) + _n_arrays(
+                        (old, snaps, self.slabs))
                 # no-event fused tick (the common case): the plain slab
                 # step is the same single dispatch minus the ring
                 # plumbing — the fused win here is skipping the per-tick
                 # readback, not the kernel shape
-                self.slabs, logits = self._step(self.plans, self.slabs,
-                                                frames, *masks)
+                self.slabs, logits = self._step(
+                    self._consts[self.primary], self.slabs, frames, *masks)
+                self.call_arrays += self._step_arrays[self.primary]
             self.device_dispatches += 1
             if group_args:
                 logits = self._step_groups(frames, group_args, logits)
@@ -1136,7 +1170,7 @@ class GcnService:
         :meth:`_warm` pre-compiles every pair.  Same primitives as QoS
         preemption, so the migrated-session parity invariant is the
         preemption invariant."""
-        jax, jnp = self._jax, self._jnp
+        jax = self._jax
         t0 = time.monotonic()
         S_old = self.capacity
         occupied = [s for s, slot in enumerate(self.sched.slots)
@@ -1144,8 +1178,10 @@ class GcnService:
         mapping = self.sched.resize(new_S)
         free = [s for s in range(S_old) if s not in mapping]
         k = min(S_old, new_S)
-        old_idx = jnp.asarray((occupied + free)[:k], jnp.int32)
-        new_idx = jnp.arange(k, dtype=jnp.int32)   # == mapped targets
+        # host int32 index arrays: an eager jnp conversion would compile
+        # a one-off program at the first migration, after warm-up
+        old_idx = np.asarray((occupied + free)[:k], np.int32)
+        new_idx = np.arange(k, dtype=np.int32)     # == mapped targets
         new_slabs = tuple(
             self._migrate_fn(slab, nsl, old_idx, new_idx)
             for slab, nsl in zip(self.slabs, self._tier_slabs[new_S]))
@@ -1337,6 +1373,7 @@ class GcnService:
             "wall_device_s": self.wall_device_s,
             "tick_path": "fused" if self.fused else "legacy",
             "device_dispatches": self.device_dispatches,
+            "call_arrays": self.call_arrays,
             "frames_per_s": sched.valid_frames / wall if wall > 0 else 0.0,
             "ticks_per_s": ticks / wall if wall > 0 else 0.0,
             "occupancy": occ_time,

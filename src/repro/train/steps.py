@@ -155,9 +155,10 @@ def make_gcn_slab_step(cfg: ModelConfig) -> Callable:
     admission/eviction logic lives in ``repro.serving``.
 
     ``stats`` (keyword, optional) is a per-stream tuple of frozen BN
-    statistics overriding each slab's own calibration for this tick — the
-    multi-topology service's per-skeleton dispatch; ``None`` keeps the
-    slabs' stats (single-topology path, unchanged)."""
+    statistics overriding each slab's own calibration for this tick — how
+    the service steps its bare slabs (:func:`on_packed_constants`), one
+    topology's statistics per dispatch; ``None`` keeps the slabs' own
+    stats."""
     from repro.core.agcn import engine
 
     def slab_step(plans, slabs, frames, valid, reset, hold=None, stats=None):
@@ -207,6 +208,25 @@ def make_gcn_fused_tick(cfg: ModelConfig) -> Callable:
         return (s0,), logits, (r0,)
 
     return fused_tick
+
+
+def on_packed_constants(step: Callable) -> Callable:
+    """The serving tick's call form of :func:`make_gcn_slab_step` or
+    :func:`make_gcn_fused_tick`: ``tick(consts, slabs, *args)``, where
+    ``consts`` is an ``engine.PackedConstants`` of ``(plans, stats)`` —
+    the ExecutionPlans and their frozen BN statistics in one buffer per
+    dtype — and ``slabs`` carry per-slot state only (empty ``bn_stats``).
+    The plans and statistics are unpacked inside the trace and the
+    statistics ride ``step``'s ``stats`` override, so the jitted call
+    takes and returns a third of the arrays the unpacked form does and
+    computes the same thing bit for bit.  ``consts`` is argument 0 and is
+    never donated."""
+
+    def packed(consts, slabs, *args):
+        plans, stats = consts.unpack()
+        return step(plans, slabs, *args, stats=stats)
+
+    return packed
 
 
 def make_serve_step(cfg: ModelConfig) -> Callable:

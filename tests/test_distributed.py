@@ -327,8 +327,9 @@ def test_run_routed_sessions_row(params, prune_plan):
 @needs4
 def test_router_replicas_pinned_one_per_device():
     """``ReplicaRouter.build`` places replica i on device i: its plans, BN
-    stats, slab and snapshot-ring leaves live there, and stay there
-    through ticks — four replicas never share one device."""
+    stats, packed tick constants, slab and snapshot-ring leaves live
+    there, and stay there through ticks — four replicas never share one
+    device."""
     router = ReplicaRouter.build(CFG, replicas=4, capacity_tiers=(2,))
     devs = jax.devices()[:4]
     rng = np.random.default_rng(9)
@@ -339,7 +340,8 @@ def test_router_replicas_pinned_one_per_device():
     for _ in range(3):
         router.tick()
     for i, svc in enumerate(router.services):
-        trees = (svc.plans, svc.bn_stats, svc.slabs, svc._rings)
+        trees = (svc.plans, svc.bn_stats, svc._consts, svc.slabs,
+                 svc._rings)
         leaves = jax.tree_util.tree_leaves(trees)
         assert leaves
         assert all(leaf.devices() == {devs[i]} for leaf in leaves), i

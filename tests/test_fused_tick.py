@@ -21,6 +21,8 @@ defers surplus preemptions (never overflows the static buffers), the
 snapshot-ring allocator raises on exhaustion, and the jax-free sentinel
 mirror in the scheduler equals the engine's.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -138,21 +140,22 @@ def test_fused_no_retrace_across_event_counts(params, prune_plan):
     plan, bn = _plan_and_bn(params, prune_plan, "reference")
     svc = GcnService(CFG, plans=(plan,), bn_stats=(bn,), qos="preempt",
                      capacity_tiers=(2,), warm=False, fused=True)
-    from repro.train.steps import make_gcn_fused_tick, make_gcn_slab_step
-    inner = make_gcn_fused_tick(CFG)
-    inner_step = make_gcn_slab_step(CFG)
+    from repro.train.steps import (make_gcn_fused_tick, make_gcn_slab_step,
+                                   on_packed_constants)
+    inner = on_packed_constants(make_gcn_fused_tick(CFG))
+    inner_step = on_packed_constants(make_gcn_slab_step(CFG))
     traces = []
     step_traces = []
 
-    def counted(plans, slabs, frames, valid, reset, hold,
+    def counted(consts, slabs, frames, valid, reset, hold,
                 snap_order, rest_order, rings):
         traces.append(1)
-        return inner(plans, slabs, frames, valid, reset, hold,
+        return inner(consts, slabs, frames, valid, reset, hold,
                      snap_order, rest_order, rings)
 
-    def counted_step(plans, slabs, frames, valid, reset, hold):
+    def counted_step(consts, slabs, frames, valid, reset, hold):
         step_traces.append(1)
-        return inner_step(plans, slabs, frames, valid, reset, hold)
+        return inner_step(consts, slabs, frames, valid, reset, hold)
 
     svc._fused_tick = jax.jit(counted, donate_argnums=(1, 8))
     svc._step = jax.jit(counted_step)
@@ -283,3 +286,198 @@ def test_poll_async_default_never_forces_readback(params, prune_plan):
     assert m["device_dispatches"] == m["ticks"]   # polling added none
     assert svc.poll(h).state == "done"
     assert np.isfinite(svc.poll(h).logits).all()
+
+
+# ---------------------------------------------------------------------------
+# packed constant operands (engine.pack_constants, steps.on_packed_constants)
+# ---------------------------------------------------------------------------
+
+def _assert_bits(got, want, what=""):
+    """Two pytrees with the same structure (statics included) and every
+    leaf equal in dtype, shape and bytes."""
+    gl, gt = jax.tree_util.tree_flatten(got)
+    wl, wt = jax.tree_util.tree_flatten(want)
+    assert gt == wt, f"{what}: tree structure differs"
+    for i, (g, w) in enumerate(zip(gl, wl)):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape, (what, i)
+        assert g.tobytes() == w.tobytes(), f"{what}: leaf {i} differs"
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_unpack_of_pack_is_bit_identical(params, prune_plan, backend):
+    """unpack(pack(plans, stats)) gives back the plans and the frozen BN
+    statistics leaf for leaf, bit for bit — eagerly and inside a trace —
+    with one buffer per dtype, no leaf changing dtype, and every leaf on
+    an aligned offset."""
+    plan, bn = _plan_and_bn(params, prune_plan, backend)
+    tree = ((plan, plan), (bn, bn))
+    packed = engine.pack_constants(tree)
+    leaves = jax.tree_util.tree_leaves(tree)
+    kinds = {np.asarray(x).dtype.name for x in leaves}
+    assert {"float32", "int32"} <= kinds
+    assert len(packed.buffers) == len(kinds)
+    assert [b.dtype.name for b in packed.buffers] == sorted(kinds)
+    assert len(packed.layout.leaves) == len(leaves)
+    assert all(off % engine.PACK_ALIGN == 0
+               for _, off, _ in packed.layout.leaves)
+    _assert_bits(packed.unpack(), tree, "eager unpack")
+    _assert_bits(jax.jit(lambda c: c.unpack())(packed), tree, "traced")
+    # equal layouts are one jit-cache key
+    again = engine.pack_constants(tree)
+    assert again.layout == packed.layout
+    assert hash(again.layout) == hash(packed.layout)
+
+
+class _Shadow:
+    """Wraps a service's jitted tick entry points: before each packed
+    call, the same call runs in the unpacked form — ``make_gcn_slab_step``
+    / ``make_gcn_fused_tick`` on the unpacked plans and a slab that
+    carries its BN statistics, as the service called them before its
+    constants were packed — and every output is compared bit for bit."""
+
+    def __init__(self, svc):
+        from repro.train.steps import make_gcn_fused_tick, make_gcn_slab_step
+
+        self.plain_step = jax.jit(make_gcn_slab_step(CFG))
+        self.plain_fused = jax.jit(make_gcn_fused_tick(CFG))
+        self.step, self.fused = svc._step, svc._fused_tick
+        self.calls = {"step": 0, "fused": 0}
+        svc._step, svc._fused_tick = self.check_step, self.check_fused
+
+    @staticmethod
+    def _unpacked(consts, slabs):
+        plans, stats = consts.unpack()
+        assert all(s.bn_stats == {} for s in slabs)
+        return plans, tuple(dataclasses.replace(s, bn_stats=st)
+                            for s, st in zip(slabs, stats))
+
+    @staticmethod
+    def _bare(slabs):
+        return tuple(dataclasses.replace(s, bn_stats={}) for s in slabs)
+
+    def check_step(self, consts, slabs, *args):
+        want_slabs, want = jax.block_until_ready(
+            self.plain_step(*self._unpacked(consts, slabs), *args))
+        got_slabs, got = self.step(consts, slabs, *args)
+        _assert_bits(got, want, "step logits")
+        _assert_bits(got_slabs, self._bare(want_slabs), "step slabs")
+        self.calls["step"] += 1
+        return got_slabs, got
+
+    def check_fused(self, consts, slabs, *args):
+        # the packed call donates its slabs and rings: compute the
+        # unpacked form first
+        want = jax.block_until_ready(
+            self.plain_fused(*self._unpacked(consts, slabs), *args))
+        got = self.fused(consts, slabs, *args)
+        _assert_bits(got[1], want[1], "fused logits")
+        _assert_bits(got[0], self._bare(want[0]), "fused slabs")
+        _assert_bits(got[2], want[2], "fused rings")
+        self.calls["fused"] += 1
+        return got
+
+
+def _mixed_trace(rng):
+    """X (priority 0, ntu25) and Y (priority 1, ntu50) fill a 2-slot slab;
+    Z (priority 2, ntu25) arrives at tick 5 and preempts X, which is
+    restored when a slot frees."""
+    spec = [(0, 0, "ntu25", 10), (0, 1, "ntu50", 12), (5, 2, "ntu25", 8)]
+    out = []
+    for i, (a, p, topo, T) in enumerate(spec):
+        v = 50 if topo == "ntu50" else 25
+        out.append(SessionRequest(
+            sid=i, arrival=a, priority=p, topology=topo,
+            clip=rng.standard_normal((T, v, C)).astype(np.float32)))
+    return out
+
+
+def _drive_topology_requests(svc, reqs, max_ticks=600):
+    """As ``_drive_requests``, opening each session on its topology."""
+    pending = sorted(reqs, key=lambda r: r.arrival)
+    i = 0
+    while svc.now < max_ticks:
+        while i < len(pending) and pending[i].arrival <= svc.now:
+            r = pending[i]
+            h = svc.open_session(priority=r.priority, arrival=r.arrival,
+                                 topology=r.topology)
+            svc.submit_clip(h, r.clip)
+            i += 1
+        if svc.idle():
+            if i == len(pending):
+                break
+            svc.advance_clock(pending[i].arrival)
+            continue
+        svc.tick()
+    assert svc.idle(), "service did not drain within the tick budget"
+    return svc.metrics()
+
+
+PACKED_CASES = {
+    # preemptions, restores (fused event ticks) and an elastic grow/shrink
+    "single-elastic": dict(topologies=("ntu25",), capacity_tiers=(2, 4)),
+    # two skeletons in one slab: one packed-constants dispatch per group
+    "mixed": dict(topologies=("ntu25", "ntu50"), capacity_tiers=(2,)),
+}
+
+
+@pytest.mark.parametrize("case", list(PACKED_CASES))
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_packed_tick_matches_unpacked_step(backend, case):
+    """Every jitted call of the service's tick — plain slab steps, fused
+    event ticks with preemption and restore, each skeleton group's
+    dispatch, ticks before and after an elastic migration — returns the
+    same slabs, logits and snapshot rings, bit for bit, as the unpacked
+    per-tick step on the unpacked plans and statistics."""
+    kw = PACKED_CASES[case]
+    ccfg = CapacityConfig(tiers=kw["capacity_tiers"], grow_patience=1,
+                          shrink_patience=2, cooldown=3)
+    svc = GcnService(CFG, backend=backend, qos="preempt", seed=0,
+                     capacity_config=ccfg, warm=False, **kw)
+    shadow = _Shadow(svc)
+    rng = np.random.default_rng(7)
+    if case == "mixed":
+        m = _drive_topology_requests(svc, _mixed_trace(rng))
+    else:
+        _, m = _drive_requests(svc, _qos_trace(rng))
+        assert m["migrations"] > 0
+    assert m["preemptions"] > 0 and m["restores"] > 0
+    assert shadow.calls["fused"] > 0 and shadow.calls["step"] > 0
+    assert m["device_dispatches"] == sum(shadow.calls.values())
+
+
+_COMPILES = {"on": False, "n": 0}
+
+
+def _count_compiles(name, secs, **kw):
+    if _COMPILES["on"] and name in (
+            "/jax/core/compile/jaxpr_trace_duration",
+            "/jax/core/compile/backend_compile_duration"):
+        _COMPILES["n"] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compiles)
+
+
+@pytest.mark.parametrize("case", list(PACKED_CASES))
+def test_packed_tick_compiles_nothing_after_warm_up(case):
+    """Once the service is built and warmed, its packed-constant ticks
+    compile nothing: not across event counts, skeleton groups or an
+    elastic migration."""
+    kw = PACKED_CASES[case]
+    ccfg = CapacityConfig(tiers=kw["capacity_tiers"], grow_patience=1,
+                          shrink_patience=2, cooldown=3)
+    svc = GcnService(CFG, backend="reference", qos="preempt", seed=0,
+                     capacity_config=ccfg, **kw)
+    rng = np.random.default_rng(7)
+    _COMPILES.update(on=True, n=0)
+    try:
+        if case == "mixed":
+            m = _drive_topology_requests(svc, _mixed_trace(rng))
+        else:
+            _, m = _drive_requests(svc, _qos_trace(rng))
+            assert m["migrations"] > 0
+    finally:
+        _COMPILES["on"] = False
+    assert m["preemptions"] > 0 and m["restores"] > 0
+    assert _COMPILES["n"] == 0

@@ -258,13 +258,13 @@ def test_no_retrace_within_tier(params):
                      warm=False, fused=False)
     # count traces of the service's own step by re-jitting a counting
     # wrapper around the same step factory the service uses
-    from repro.train.steps import make_gcn_slab_step
-    inner = make_gcn_slab_step(CFG)
+    from repro.train.steps import make_gcn_slab_step, on_packed_constants
+    inner = on_packed_constants(make_gcn_slab_step(CFG))
     traces = []
 
-    def counted(plans, slabs, frames, valid, reset, hold):
+    def counted(consts, slabs, frames, valid, reset, hold):
         traces.append(1)
-        return inner(plans, slabs, frames, valid, reset, hold)
+        return inner(consts, slabs, frames, valid, reset, hold)
 
     svc._step = jax.jit(counted)
     rng = np.random.default_rng(3)
@@ -279,6 +279,52 @@ def test_no_retrace_within_tier(params):
     svc.run_until_idle()
     assert svc.poll(h0).state == "done" and svc.poll(h1).state == "done"
     assert len(traces) == 1
+
+
+@pytest.mark.parametrize("topologies", [("ntu25",), ("ntu25", "ntu50")],
+                         ids=["single", "mixed"])
+def test_call_arrays_counts_bare_slabs_and_packed_constants(topologies):
+    """``call_arrays`` grows, per jitted tick call, by 2 x the slabs'
+    per-slot leaves (in and out) + the packed constant buffers + frames
+    and 3 masks + the logits — the arrays that really cross the call —
+    and no plan array or frozen BN statistic crosses it."""
+    svc = GcnService(CFG, backend="reference", capacity_tiers=(2,),
+                     topologies=topologies, seed=0, warm=False)
+    per_slot = sum(len(jax.tree_util.tree_leaves(
+        engine.snapshot_slots(s, 0))) for s in svc.slabs)
+    plan_ids = {id(x) for x in jax.tree_util.tree_leaves(
+        (svc.plans, svc.bn_stats))}
+    inner = svc._step
+    seen = []
+
+    def recorded(consts, slabs, *args):
+        assert isinstance(consts, engine.PackedConstants)
+        assert all(s.bn_stats == {} for s in slabs)
+        leaves = jax.tree_util.tree_leaves((consts, slabs, args))
+        assert not plan_ids & {id(x) for x in leaves}
+        assert len(jax.tree_util.tree_leaves(slabs)) == per_slot
+        out = inner(consts, slabs, *args)
+        seen.append(len(leaves) + len(jax.tree_util.tree_leaves(out)))
+        assert seen[-1] == 2 * per_slot + len(consts.buffers) + 4 + 1
+        return out
+
+    svc._step = recorded
+    rng = np.random.default_rng(5)
+    for t in topologies:
+        h = svc.open_session(topology=t)
+        vt = svc._topos[t].num_joints
+        svc.submit_clip(h, rng.standard_normal((6, vt, C))
+                        .astype(np.float32))
+    per_tick = []
+    while not svc.idle():
+        before, n = svc.call_arrays, len(seen)
+        svc.tick()
+        per_tick.append(svc.call_arrays - before)
+        assert per_tick[-1] == sum(seen[n:])
+    # one dispatch per occupied skeleton group, on every tick
+    assert len(seen) == svc.metrics()["device_dispatches"]
+    assert max(per_tick) == len(topologies) * seen[0]
+    assert svc.metrics()["call_arrays"] == sum(per_tick)
 
 
 def test_capacity_manager_hysteresis_never_thrashes():
