@@ -27,6 +27,11 @@ SERVE_BATCH_DEFAULTS = {
     "default": 4,        # LM families (decode batch)
 }
 
+# Forms of the 2s-AGCN data-dependent graph C_k (repro.core.agcn.adaptive):
+# "window" pools θ/φ over a trailing window of frames, so live streams can
+# compute it; "clip" is the published form, pooled over the whole clip.
+CK_FORMS = ("window", "clip")
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -89,7 +94,10 @@ class ModelConfig:
     gcn_strides: Tuple[int, ...] = ()
     gcn_kv: int = 3                        # K_v neighbour subsets
     gcn_tkernel: int = 9                   # temporal kernel size
-    use_ck: bool = False                   # windowed data-dependent C_k graph
+    use_ck: bool = False                   # data-dependent C_k graph
+    ck_form: str = "window"                # C_k form (CK_FORMS): "window" =
+                                           # trailing-window streaming form,
+                                           # "clip" = published whole-clip
 
     # --- paper technique knobs (first-class features) ---
     prune_channel_fracs: Tuple[float, ...] = ()  # per-block kept fraction (C1)
@@ -120,6 +128,9 @@ class ModelConfig:
                                            # temp fits 16 GB/chip HBM
 
     def __post_init__(self):
+        if self.ck_form not in CK_FORMS:
+            raise ValueError(f"unknown ck_form {self.ck_form!r} "
+                             f"(expected one of {CK_FORMS})")
         if self.head_dim == 0 and self.num_heads:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
 

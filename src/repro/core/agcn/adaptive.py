@@ -1,12 +1,30 @@
-"""Windowed data-dependent C_k graphs — the adaptive-streaming reformulation.
+"""The data-dependent graph C_k of 2s-AGCN, in its two forms
+(``ModelConfig.ck_form``).
 
-The paper drops the data-dependent similarity graph C_k at deployment
-(Table I: 88.9% w/o C_k) because eq. (1) pools embeddings over the *whole
-clip's* time axis — a live stream has no clip to pool over.  This module
-reformulates C_k as a **trailing-window** statistic so the same graph is
-computable per frame from the streaming engine's existing ring buffers
-(Continual ST-GCN, PAPERS.md 2203.11009, applies the same per-frame
-continual rewrite to these blocks):
+**clip** — the published C_k (Shi et al., arXiv:1805.07694, the
+reference code's ``unit_gcn``), for the clip path only: a live stream has
+no whole clip, so the session slab and ``GcnService`` refuse this form.
+Per subset k, with
+θ_k/φ_k 1×1 convolutions C_in → Ce = C_out/4 with biases:
+
+    C_k[v, w] = softmax_v( Σ_{c<Ce, t<T} θ_k[c,t,v]·φ_k[c,t,w] / (Ce·T) )
+
+pooled over the *whole clip*, and the block aggregates
+``out[w] = Σ_v x[v]·(A_k + B_k + C_k)[v, w]``.  This repo's graphs are
+``G[w, v]`` (joint v weighted into joint w), so :func:`clip_ck` returns
+the transpose, one graph per sample and subset, softmax over its last
+(input-joint) axis; the engine adds it to ``A_k + B_k`` and the Pallas
+backend computes it with ``ops.clip_similarity`` and aggregates with the
+per-sample ``ops.graph_sconv_rows``.
+
+**window** — the adaptive-streaming reformulation, for live streams.
+The paper drops C_k at deployment (Table I: 88.9% w/o C_k) because the
+published form pools over the whole clip — a live stream has no clip to
+pool over.  This form pools over a **trailing window** instead, so the
+same graph is computable per frame from the streaming engine's existing
+ring buffers (Continual ST-GCN, PAPERS.md 2203.11009, applies the same
+per-frame continual rewrite to these blocks), with one θ/φ per block
+shared by the subsets, Ce = C_in/4 and no biases:
 
     Θ(t) = Σ_{u=t−K+1..t} θ(x_u)          (zeros before the stream starts)
     Φ(t) = Σ_{u=t−K+1..t} φ(x_u)
@@ -23,16 +41,17 @@ streaming logits match clip logits ≤1e-3 with C_k **on**
 (tests/test_streaming.py) — the invariant the full-clip eq. (1) could
 never satisfy.
 
-Normalization matches :func:`repro.core.agcn.graph.similarity_graph`
-(logits scaled by 1/√Ce, max-subtracted softmax over the input-joint
-axis); slab-padded joints are masked out of the softmax *columns* so a
-padded plan's graph rows never pool from dead joints.
+In both forms the softmax runs over the input-joint axis (the last axis
+of a ``G[out, in]`` graph), max-subtracted, and slab-padded joints are
+masked out of the softmax *columns* so a padded plan's graph rows never
+pool from dead joints.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-__all__ = ["windowed_ck", "clip_windowed_ck"]
+__all__ = ["windowed_ck", "clip_windowed_ck", "clip_ck"]
 
 
 def windowed_ck(win_th: jnp.ndarray, win_ph: jnp.ndarray,
@@ -89,3 +108,26 @@ def clip_windowed_ck(x: jnp.ndarray, w_theta: jnp.ndarray,
     return windowed_ck(_trailing_window_sum(th, k),
                        _trailing_window_sum(ph, k),
                        valid_joints=valid_joints)
+
+
+def clip_ck(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, kv: int,
+            valid_joints: int = 0) -> jnp.ndarray:
+    """The published whole-clip C_k: (N, T, V, C) -> (N, K, V, V).
+
+    ``w`` (C, 2·K·Ce) holds θ_0 … θ_{K-1} then φ_0 … φ_{K-1} and ``b``
+    their biases (the plan's ``ck_w`` / ``ck_b``).  Returns
+    ``out[n, k, i, j] = softmax_j(Σ_{c,t} φ_k[n,t,i,c]·θ_k[n,t,j,c] /
+    (Ce·T))`` — the published ``C_k[j, i]`` in this repo's orientation.
+    Input-joint columns ≥ ``valid_joints`` (0 = all of V) are masked, as
+    in :func:`windowed_ck`.  The Pallas twin is
+    ``repro.kernels.ops.clip_similarity``."""
+    N, T, V, _ = x.shape
+    e = jnp.einsum("ntvc,cf->ntvf", x, w.astype(x.dtype)) + b.astype(x.dtype)
+    ce = e.shape[-1] // (2 * kv)
+    e = e.reshape(N, T, V, 2, kv, ce)
+    logits = jnp.einsum("ntike,ntjke->nkij", e[..., 1, :, :],
+                        e[..., 0, :, :]) / jnp.asarray(ce * T, x.dtype)
+    if 0 < valid_joints < V:
+        dead = jnp.arange(V) >= valid_joints            # (V,) input joints
+        logits = jnp.where(dead, jnp.asarray(-1e30, logits.dtype), logits)
+    return jax.nn.softmax(logits, axis=-1).astype(x.dtype)

@@ -52,6 +52,14 @@ from repro.kernels import ops
 
 BACKENDS = ("reference", "pallas")
 
+# why streaming (step_frame, the session slab, GcnService) refuses the
+# published C_k
+STREAMING_CK_REFUSAL = (
+    "ck_form='clip' is the published C_k, pooled over the whole clip: a "
+    "live stream has no whole clip to pool over, so streaming and session "
+    "serving run ck_form='window' (the trailing-window form); run the "
+    "published form on the clip path (engine.execute)")
+
 
 # ---------------------------------------------------------------------------
 # plan containers
@@ -71,6 +79,7 @@ class BlockStatic:
     pruned_in: bool          # kept_in gather present
     pruned_filters: bool     # kept_filters scatter present
     sconv: str = "dense"     # spatial-conv path: "dense" | "csr"
+    ck_form: str = "window"  # C_k form when use_ck ("window" | "clip")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -289,9 +298,17 @@ class Backend(Protocol):
                 bs: BlockStatic,
                 ck: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         """Graph spatial conv Σ_k (G_k·x)·W_k: (N,T,V,Cin) -> (N,T,V,Cout).
-        ``ck`` optionally adds a precomputed per-frame data-dependent
-        graph (N,T,V,V) to every subset's G_k (the windowed C_k path —
-        repro.core.agcn.adaptive)."""
+        ``ck`` optionally adds a precomputed data-dependent graph to G_k
+        (repro.core.agcn.adaptive): per frame (N,T,V,V) to every subset
+        in the windowed form, per sample and subset (N,K,V,V) in the
+        published clip form."""
+        ...
+
+    def clip_ck(self, x: jnp.ndarray, ba: Dict[str, Any],
+                valid_joints: int) -> jnp.ndarray:
+        """The published whole-clip C_k of a block input with kept
+        channels gathered: (N,T,V,C) -> (N,K,V,V), in the graph
+        orientation ``G[out, in]``."""
         ...
 
     def temporal(self, x: jnp.ndarray, ba: Dict[str, Any],
@@ -320,11 +337,13 @@ def _spatial_einsum(x: jnp.ndarray, ba: Dict[str, Any],
                     ck: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Reference math for Σ_k (G_k·x)·W_k (+ optional data-dependent C_k).
 
-    ``ck`` is a precomputed per-frame (N, T, V, V) windowed similarity
-    graph (repro.core.agcn.adaptive) added to every subset's static
-    ``A_k + B_k`` — the engine computes it (clip: per frame index;
-    streaming: from the embedding rings) because the window state and the
-    padded-joint masking live above the backend.  A plan padded to a slab
+    ``ck`` is a precomputed data-dependent graph (repro.core.agcn.
+    adaptive) added to the static ``A_k + B_k``: the windowed form's
+    per-frame (N, T, V, V) graph, added to every subset — the engine
+    computes it (clip: per frame index; streaming: from the embedding
+    rings) because the window state and the padded-joint masking live
+    above the backend — or the published form's per-sample, per-subset
+    (N, K, V, V) graph (``bs.ck_form == "clip"``).  A plan padded to a slab
     Vmax may be run on a clip at the topology's own joint count (BN
     calibration); the padded graph is zero outside its valid joints, so
     slicing it down to x's V is exact."""
@@ -332,6 +351,9 @@ def _spatial_einsum(x: jnp.ndarray, ba: Dict[str, Any],
     if G.shape[-1] != x.shape[2]:
         G = G[:, : x.shape[2], : x.shape[2]]
     Wk = ba["Wk"].astype(x.dtype)
+    if ck is not None and bs.ck_form == "clip":
+        Gn = G[None] + ck.astype(x.dtype)                    # (N,K,V,V)
+        return jnp.einsum("ntvc,nkwv,kco->ntwo", x, Gn, Wk)
     if ck is not None:
         Gn = G[None, None] + ck.astype(x.dtype)[:, :, None]  # (N,T,K,V,V)
         y = jnp.einsum("ntvc,ntkwv->ntkwc", x, Gn)
@@ -371,6 +393,11 @@ class ReferenceBackend:
             return _spatial_csr_ref(xg, ba, bs)
         return _spatial_einsum(xg, ba, bs, ck=ck)
 
+    def clip_ck(self, x, ba, valid_joints):
+        """The published C_k in jnp (``adaptive.clip_ck``)."""
+        return adaptive.clip_ck(x, ba["ck_w"], ba["ck_b"],
+                                int(ba["Wk"].shape[0]), valid_joints)
+
     def temporal(self, x, ba, bs):
         """Dense masked temporal conv, 'same' padding, stride on T; pruned
         filters are scattered back to full width for the residual path."""
@@ -408,11 +435,13 @@ class PallasBackend:
     """Fused Pallas kernels; RFC roundtrip is the inter-layer format.
 
     The data-dependent C_k graph cannot be precompiled (it is a function
-    of the activations), so blocks with ``use_ck`` apply it through the
-    reference einsum — the graph itself comes precomputed via ``ck``
-    (streaming builds it with the fused ``ops.windowed_similarity``
-    kernel over the embedding rings; with C_k off the paper's
-    deployment path, Table I, is unchanged).
+    of the activations).  Published (clip-form) C_k blocks stay in the
+    kernels: ``ops.clip_similarity`` computes each sample's graphs and
+    the per-sample ``ops.graph_sconv_rows`` aggregates with
+    ``A_k + B_k + C_k``.  Windowed C_k blocks apply their per-frame graph
+    (built by streaming with the fused ``ops.windowed_similarity`` kernel
+    over the embedding rings) through the reference einsum.  With C_k off
+    the paper's deployment path, Table I, is unchanged.
     """
 
     name = "pallas"
@@ -423,9 +452,18 @@ class PallasBackend:
     def spatial(self, x, ba, bs, ck=None):
         """Fused graph+1×1 kernel (``ops.graph_sconv``) on the padded
         (K, Vp, Vp) plan graph, or the ELL gather kernel when the plan
-        chose ``sconv="csr"``; C_k blocks apply the precomputed ``ck``
-        through the einsum."""
+        chose ``sconv="csr"``; published C_k blocks run the per-sample
+        kernel (``ops.graph_sconv_rows``) on ``Gp + ck``, windowed C_k
+        blocks apply the precomputed ``ck`` through the einsum."""
         xg = _gather_in(x, ba)
+        if bs.use_ck and bs.ck_form == "clip":
+            gp = ba["Gp"]
+            pad = gp.shape[-1] - ck.shape[-1]
+            g = gp[None] + jnp.pad(ck.astype(gp.dtype),
+                                   ((0, 0), (0, 0), (0, pad), (0, pad)))
+            return shard_batch(
+                lambda vg, w: ops.graph_sconv_rows(
+                    *vg, w, interpret=self.interpret), (xg, g), ba["Wk"])
         if bs.use_ck:
             return _spatial_einsum(xg, ba, bs, ck=ck)
         if bs.sconv == "csr":
@@ -437,6 +475,15 @@ class PallasBackend:
             lambda v, g, w: ops.graph_sconv(v, g, w,
                                             interpret=self.interpret),
             xg, ba["Gp"], ba["Wk"])
+
+    def clip_ck(self, x, ba, valid_joints):
+        """The published C_k through the ``ck_proj`` and ``ck_sim``
+        kernels (``ops.clip_similarity``)."""
+        kv = int(ba["Wk"].shape[0])
+        return shard_batch(
+            lambda v, w, b: ops.clip_similarity(
+                v, w, b, kv, valid_joints, interpret=self.interpret),
+            x, ba["ck_w"], ba["ck_b"])
 
     def temporal(self, x, ba, bs):
         """Packed cavity tconv kernel over the flattened (N·V, T, C) rows —
@@ -605,6 +652,7 @@ def build_execution_plan(
         cout = int(blk["tconv_w"].shape[0])
         cin_full = int(blk["Wk"].shape[1])            # pre-gather block input
         use_ck = bool(cfg.use_ck and "theta" in blk)
+        ck_form = cfg.ck_form if use_ck else "window"
 
         # --- spatial: graph precompute + kept-channel gather + quant ------
         if int(blk["Bk"].shape[-1]) != vj:
@@ -628,8 +676,23 @@ def build_execution_plan(
             kept_in = jnp.asarray(pb.kept_in, jnp.int32)
             Wk = jnp.take(Wk, kept_in, axis=1)
             if use_ck:
-                theta = jnp.take(theta, kept_in, axis=0)
-                phi = jnp.take(phi, kept_in, axis=0)
+                theta = jnp.take(theta, kept_in, axis=-2)
+                phi = jnp.take(phi, kept_in, axis=-2)
+        ck_w = ck_b = None
+        if ck_form == "clip":
+            # the published per-subset θ_k/φ_k as one (C, 2·K·Ce)
+            # projection, θ_0 … θ_{K-1} then φ_0 … φ_{K-1}
+            if theta.ndim != 3 or "theta_b" not in blk:
+                raise ValueError(
+                    f"block {b}: ck_form='clip' needs per-subset θ_k/φ_k "
+                    f"(K, C, Ce) with biases; the params carry "
+                    f"{tuple(theta.shape)} (the windowed form's)")
+            ck_w = jnp.concatenate(
+                [jnp.transpose(m, (1, 0, 2)).reshape(m.shape[1], -1)
+                 for m in (theta, phi)], axis=-1)
+            ck_b = jnp.concatenate([blk["theta_b"].reshape(-1),
+                                    blk["phi_b"].reshape(-1)])
+            theta = phi = None
 
         # --- temporal: filter gather + cavity mask + quant ----------------
         tw = blk["tconv_w"]                           # (F, C, K)
@@ -647,6 +710,8 @@ def build_execution_plan(
         n_kept = int(tw.shape[0])
 
         # --- spatial path selection: dense padded vs CSR ------------------
+        # a C_k block's graph A + B_k + C_k is data-dependent and dense
+        # (a softmax has no zeros), so it always takes the dense path
         block_sconv = "dense"
         if sconv != "dense" and not use_ck:
             density = _graph_density(Gv, csr_eps)
@@ -661,7 +726,7 @@ def build_execution_plan(
 
         ba: Dict[str, Any] = {
             "G": G, "Wk": Wk, "kept_in": kept_in,
-            "theta": theta, "phi": phi,
+            "theta": theta, "phi": phi, "ck_w": ck_w, "ck_b": ck_b,
             "bn_s": blk["bn_s"], "bn_t": blk["bn_t"],
             "tw": tw, "tb": tb, "kept_filters": kept_filters,
             "down_w": blk.get("down_w"), "bn_down": blk.get("bn_down"),
@@ -699,10 +764,10 @@ def build_execution_plan(
             ba["taps"] = jnp.asarray(taps)
             ba["inv_perm"] = jnp.asarray(inv, jnp.int32)
             # drop the dense forms the pallas path never reads — they'd ride
-            # every jit call as dead payload (G stays only for the C_k
-            # fallback, which runs the reference einsum)
+            # every jit call as dead payload (G stays only for the windowed
+            # C_k blocks, which run the reference einsum)
             ba["tw"] = None
-            if not use_ck:
+            if not use_ck or ck_form == "clip":
                 ba["G"] = None
 
         blocks_a.append(ba)
@@ -712,7 +777,7 @@ def build_execution_plan(
             tkernel=int(cfg.gcn_tkernel), use_ck=use_ck,
             pruned_in=kept_in is not None,
             pruned_filters=kept_filters is not None,
-            sconv=block_sconv,
+            sconv=block_sconv, ck_form=ck_form,
         ))
 
     input_skip = (prune_plan.input_skip if prune_plan is not None
@@ -779,7 +844,12 @@ def _stem(arrays, x, input_skip: int, bn=_bn_live) -> jnp.ndarray:
 def _run_block(h, ba, bs, backend: Backend, bn=_bn_live, tag: str = "",
                vj: int = 0):
     ck = None
-    if bs.use_ck:
+    if bs.use_ck and bs.ck_form == "clip":
+        # the published C_k: one graph per sample and subset, pooled over
+        # the whole clip
+        ck = backend.clip_ck(_gather_in(h, ba),
+                             ba, vj if 0 < vj < h.shape[2] else 0)
+    elif bs.use_ck:
         # clip-mode windowed C_k: the same trailing-K recurrence the
         # streaming embedding rings evaluate, per frame index — which is
         # what makes streaming-vs-clip C_k parity a testable invariant
@@ -952,6 +1022,8 @@ def init_stream_state(
     a caller that passes the statistics to every step itself (the
     ``bn_stats`` override of :func:`step_frame`)."""
     ps = plan.static
+    if any(bs.use_ck and bs.ck_form == "clip" for bs in ps.blocks):
+        raise ValueError(STREAMING_CK_REFUSAL)
     if bn_stats is None:
         if x_calib is None:
             raise ValueError(

@@ -85,28 +85,6 @@ def graph_sparsity(a: np.ndarray) -> float:
     return float((a == 0).mean())
 
 
-def similarity_graph(x: jnp.ndarray, w_theta: jnp.ndarray, w_phi: jnp.ndarray) -> jnp.ndarray:
-    """C_k = softmax(theta(x)^T phi(x)) over joints, eq. (1).
-
-    x: (N, T, V, C); w_theta/w_phi: (C, Ce).  Returns (N, V, V).
-
-    This is the paper's *full-clip* ablation form — one graph per clip,
-    pooled over all T frames at once.  The streaming engine serves the
-    causal per-frame reformulation instead
-    (:func:`repro.core.agcn.adaptive.windowed_ck`: a trailing-K window of
-    pooled embeddings per tick), which converges to this form after the
-    drain; see tests/test_streaming.py for the parity lock.
-    """
-    theta = jnp.einsum("ntvc,ce->nve", x, w_theta)   # pool T implicitly below
-    phi = jnp.einsum("ntvc,ce->nve", x, w_phi)
-    logits = jnp.einsum("nve,nwe->nvw", theta, phi) / jnp.sqrt(
-        jnp.asarray(theta.shape[-1], x.dtype)
-    )
-    m = jnp.max(logits, axis=-1, keepdims=True)
-    e = jnp.exp(logits - m)
-    return (e / jnp.sum(e, axis=-1, keepdims=True)).astype(x.dtype)
-
-
 # ---------------------------------------------------------------------------
 # Variable-topology support: first-class GraphTopology + registry.
 # ---------------------------------------------------------------------------

@@ -1,6 +1,7 @@
 """2s-AGCN in JAX (paper §II), with the hybrid pruning plan (C1+C2) applied
-as static channel compaction, optional windowed C_k self-similarity graph
-(``repro.core.agcn.adaptive`` — streaming/clip parity by construction), Q8.8
+as static channel compaction, the optional data-dependent C_k graph
+(``repro.core.agcn.adaptive``: the published whole-clip form, or the
+windowed form with streaming/clip parity by construction), Q8.8
 quantization and input-skipping (C5).
 
 Data layout: (N, T, V, C) with the person axis M folded into N (NTU clips are
@@ -67,7 +68,15 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
             "tconv_b": jnp.zeros((cout,), jnp.float32),
             "bn_t": _bn_init(cout),
         }
-        if cfg.use_ck:
+        if cfg.use_ck and cfg.ck_form == "clip":
+            # the published unit_gcn: per-subset θ_k/φ_k, Ce = C_out/4,
+            # with biases (repro.core.agcn.adaptive.clip_ck)
+            ce = cout // 4
+            blk["theta"] = _conv_init(keys[next(ki)], (K, cin, ce), cin)
+            blk["phi"] = _conv_init(keys[next(ki)], (K, cin, ce), cin)
+            blk["theta_b"] = jnp.zeros((K, ce), jnp.float32)
+            blk["phi_b"] = jnp.zeros((K, ce), jnp.float32)
+        elif cfg.use_ck:
             ce = max(4, cin // 4)
             blk["theta"] = _conv_init(keys[next(ki)], (cin, ce), cin)
             blk["phi"] = _conv_init(keys[next(ki)], (cin, ce), cin)

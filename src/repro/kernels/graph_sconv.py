@@ -16,10 +16,14 @@ row merge is free because Vp is a sublane multiple.
 Layouts:
   x:   (R, Vp, Cin)   rows = N*T (flattened batch×time)
   g:   (K, Vp, Vp)    static + learned graph, padded to Vp
+       (N, K, Vp, Vp) per-sample graph (``graph_sconv_rows``: A_k + B_k +
+                      the published C_k of that sample)
   w:   (K, Cin, Cout)
   out: (R, Vp, Cout)
 Grid: (R tiles, Cout tiles); K is a static in-kernel loop.  The row tile
-is chosen from a VMEM budget (:func:`row_tile`), not fixed.
+is chosen from a VMEM budget (:func:`row_tile`), not fixed; with a
+per-sample graph it also divides T, so a tile never crosses a sample and
+its graph block is the one of the tile's sample.
 """
 from __future__ import annotations
 
@@ -38,15 +42,21 @@ def _lanes(n: int) -> int:
     return -(-n // 128) * 128
 
 
-def row_tile(vp: int, cin: int, co: int) -> int:
-    """Largest power-of-two row tile whose VMEM footprint fits the budget:
-    double-buffered x and out blocks, the broadcast graph, the graph-matmul
-    result and the f32 accumulator, each lane-padded to 128."""
-    per_row = 4 * vp * (3 * _lanes(cin) + 4 * _lanes(co) + _lanes(vp))
+def budget_rows(per_row: int) -> int:
+    """Largest power-of-two row tile (8 … MAX_ROW_TILE) whose footprint,
+    ``per_row`` VMEM bytes a row, fits the budget."""
     r = MAX_ROW_TILE
     while r > 8 and r * per_row > VMEM_BUDGET:
         r //= 2
     return r
+
+
+def row_tile(vp: int, cin: int, co: int) -> int:
+    """Largest power-of-two row tile whose VMEM footprint fits the budget:
+    double-buffered x and out blocks, the broadcast graph, the graph-matmul
+    result and the f32 accumulator, each lane-padded to 128."""
+    return budget_rows(4 * vp * (3 * _lanes(cin) + 4 * _lanes(co)
+                                 + _lanes(vp)))
 
 
 def _graph_conv(x, g_of_k, w_ref, kv: int):
@@ -112,6 +122,59 @@ def graph_sconv_pallas(
         out_shape=jax.ShapeDtypeStruct((R, Vp, Cout), x.dtype),
         interpret=interpret,
         name="graph_sconv",
+    )(x, g, w)
+
+
+def _rows_kernel(x_ref, g_ref, w_ref, out_ref, *, kv: int):
+    x = x_ref[...]
+    acc = _graph_conv(x, lambda k: g_ref[0, k], w_ref, kv)
+    out_ref[...] = acc.reshape(out_ref.shape).astype(out_ref.dtype)
+
+
+def sample_row_tile(t: int, vp: int, cin: int, co: int) -> int:
+    """Largest divisor of ``t`` within :func:`row_tile`'s VMEM budget: the
+    row tile of :func:`graph_sconv_rows_pallas`, whose tiles never cross
+    a sample of ``t`` rows."""
+    cap = row_tile(vp, cin, co)
+    return max(d for d in range(1, min(t, cap) + 1) if t % d == 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def graph_sconv_rows_pallas(
+    x: jnp.ndarray,      # (N*T, Vp, Cin)
+    g: jnp.ndarray,      # (N, K, Vp, Vp)
+    w: jnp.ndarray,      # (K, Cin, Cout)
+    *,
+    interpret: bool,
+) -> jnp.ndarray:
+    """Fused Σ_k (G_k·x)·W_k with a graph per sample: rows n*T .. n*T+T-1
+    of ``x`` take ``g[n]``.  (N*T, Vp, Cin) -> (N*T, Vp, Cout).  The row
+    tile divides T (:func:`sample_row_tile`), so the grid divides
+    exactly and every tile's graph block is its sample's."""
+    R, Vp, Cin = x.shape
+    N, K = g.shape[:2]
+    Cout = w.shape[-1]
+    if R % N:
+        raise ValueError(f"{R} rows do not split into {N} samples")
+    T = R // N
+    co_tile = CO_TILE if Cout % CO_TILE == 0 else Cout
+    r_tile = sample_row_tile(T, Vp, Cin, co_tile)
+    per_sample = T // r_tile
+
+    in_spec = pl.BlockSpec((r_tile, Vp, Cin), lambda r, c: (r, 0, 0))
+    g_spec = pl.BlockSpec((1, K, Vp, Vp),
+                          lambda r, c: (r // per_sample, 0, 0, 0))
+    w_spec = pl.BlockSpec((K, Cin, co_tile), lambda r, c: (0, 0, c))
+    out_spec = pl.BlockSpec((r_tile, Vp, co_tile), lambda r, c: (r, 0, c))
+
+    return pl.pallas_call(
+        functools.partial(_rows_kernel, kv=K),
+        grid=(R // r_tile, Cout // co_tile),
+        in_specs=[in_spec, g_spec, w_spec],
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct((R, Vp, Cout), x.dtype),
+        interpret=interpret,
+        name="graph_sconv_rows",
     )(x, g, w)
 
 

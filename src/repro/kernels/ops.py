@@ -18,10 +18,12 @@ import numpy as np
 from repro.kernels.cavity_tconv import (B_TILE, cavity_tconv_pallas,
                                         cavity_tconv_step_pallas)
 from repro.kernels.graph_sconv import (CO_TILE, graph_sconv_csr_pallas,
-                                       graph_sconv_pallas, row_tile)
+                                       graph_sconv_pallas,
+                                       graph_sconv_rows_pallas, row_tile)
 from repro.kernels.rfc_pack import (ROW_TILE, rfc_decode_pallas,
                                     rfc_encode_pallas)
-from repro.kernels.window_sim import windowed_similarity_pallas
+from repro.kernels.window_sim import (ck_projection_pallas, proj_row_tile,
+                                      windowed_similarity_pallas)
 
 
 def interpret_mode(platform: Optional[str] = None) -> bool:
@@ -43,7 +45,8 @@ _KERNEL_OP = re.compile(r'op_name="[^"]*/(\w+)/pallas_call"')
 
 def kernel_counts(hlo_text: str) -> Dict[str, int]:
     """Compiled Pallas kernels per kernel name (``graph_sconv``,
-    ``cavity_tconv``, ``rfc_encode`` …) in a compiled program's HLO text —
+    ``graph_sconv_rows``, ``cavity_tconv``, ``rfc_encode``, ``ck_proj``,
+    ``ck_sim``, ``window_sim`` …) in a compiled program's HLO text —
     each ``tpu_custom_call`` is one Mosaic kernel; interpreted kernels
     leave none."""
     counts: Dict[str, int] = {}
@@ -224,6 +227,48 @@ def windowed_similarity(
     return out[:, :V, :V]
 
 
+def clip_similarity(
+    x: jnp.ndarray,          # (N, T, V, C) block input, kept channels
+    w: jnp.ndarray,          # (C, 2·K·Ce) [θ_0 … θ_{K-1} | φ_0 … φ_{K-1}]
+    b: jnp.ndarray,          # (2·K·Ce,) the projections' biases
+    kv: int,
+    valid_joints: int = 0,
+    *,
+    interpret: bool,
+) -> jnp.ndarray:
+    """The published whole-clip C_k of every sample and subset.  Returns
+    (N, K, V, V) with ``out[n, k, i, j] = softmax_j(φ_k(x_n)[:, :, i] ·
+    θ_k(x_n)[:, :, j] / (Ce·T))``, the sums over every channel and frame
+    of the clip: the published ``C_k[j, i]``, in this repo's graph
+    orientation (``G[i, j]`` weights joint j into joint i).
+
+    Two kernels: ``ck_proj`` computes every θ_k/φ_k embedding in one
+    matmul per row tile; the embeddings are laid out per (sample, subset)
+    as (Vp, T·Ce) rows, lane-padded with zeros, and ``ck_sim`` (the
+    windowed-similarity kernel with K = 1) contracts and softmaxes them.
+    Input-joint columns ≥ ``valid_joints`` (0 = all of V) are masked.
+    The reference twin is :func:`repro.core.agcn.adaptive.clip_ck`."""
+    N, T, V, C = x.shape
+    F = w.shape[-1]
+    ce = F // (2 * kv)
+    xr = _pad_to(x.reshape(N * T, V, C), 1, 8)
+    Vp = xr.shape[1]
+    rt = proj_row_tile(Vp, C, F)
+    xr = _pad_to(xr, 0, rt if N * T > rt else 8)
+    e = ck_projection_pallas(
+        xr, w.astype(x.dtype), b.reshape(1, F).astype(x.dtype),
+        row_tile=min(rt, xr.shape[0]), interpret=interpret)[: N * T]
+    # (N, T, Vp, {θ,φ}, K, Ce) -> ({θ,φ}, N·K, 1, Vp, T·Ce): each (sample,
+    # subset)'s embeddings flattened over the clip
+    e = e.reshape(N, T, Vp, 2, kv, ce).transpose(3, 0, 4, 2, 1, 5)
+    e = _pad_to(e.reshape(2, N * kv, 1, Vp, T * ce), 4, 128)
+    valid = valid_joints if 0 < valid_joints < V else V
+    out = windowed_similarity_pallas(
+        e[1], e[0], valid=int(valid), scale=1.0 / (ce * T), name="ck_sim",
+        interpret=interpret)
+    return out.reshape(N, kv, Vp, Vp)[:, :, :V, :V]
+
+
 # ---------------------------------------------------------------------------
 # Fused graph + spatial conv
 # ---------------------------------------------------------------------------
@@ -281,6 +326,30 @@ def graph_sconv(
             f"(x runs {V} joints, sublane-aligned to {Vp})")
     out = graph_sconv_pallas(xr, gp, w.astype(x.dtype), interpret=interpret)
     return out[:R, :V, :].reshape(N, T, V, -1)
+
+
+def graph_sconv_rows(
+    x: jnp.ndarray,          # (N, T, V, Cin) — kept channels already gathered
+    g: jnp.ndarray,          # (N, K, V', V') per-sample graph, V' >= V
+    w: jnp.ndarray,          # (K, Cin, Cout)
+    *,
+    interpret: bool,
+) -> jnp.ndarray:
+    """Fused Σ_k (G_k[n]·x)·W_k with a graph per sample n.  Returns
+    (N, T, V, Cout).  Joints are padded to the 8-sublane multiple as in
+    :func:`graph_sconv`; a graph padded wider (a slab-padded plan) is
+    sliced down, exact because it is zero outside its valid joints."""
+    N, T, V, Cin = x.shape
+    xr = _pad_to(x.reshape(N * T, V, Cin), 1, 8)
+    Vp = xr.shape[1]
+    if g.shape[-1] < Vp:
+        pad = Vp - g.shape[-1]
+        gp = jnp.pad(g, ((0, 0), (0, 0), (0, pad), (0, pad)))
+    else:
+        gp = g[:, :, :Vp, :Vp]
+    out = graph_sconv_rows_pallas(xr, gp.astype(x.dtype), w.astype(x.dtype),
+                                  interpret=interpret)
+    return out[:, :V, :].reshape(N, T, V, -1)
 
 
 def pack_csr_ell(

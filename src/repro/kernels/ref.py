@@ -56,6 +56,14 @@ def graph_sconv_ref(x: jnp.ndarray, g: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarr
     return jnp.einsum("krwc,kco->rwo", y, w)
 
 
+def graph_sconv_rows_ref(x: jnp.ndarray, g: jnp.ndarray,
+                         w: jnp.ndarray) -> jnp.ndarray:
+    """Per-sample graphs: out[n, t] = Σ_k (G_k[n]·x[n, t])·W_k.
+    x: (N, T, V, Cin), g: (N, K, V, V), w: (K, Cin, Co)."""
+    y = jnp.einsum("ntvc,nkwv->ntkwc", x, g)
+    return jnp.einsum("ntkwc,kco->ntwo", y, w)
+
+
 def graph_sconv_csr_ref(x, indptr, indices, values, w):
     """CSR spatial conv: gather-accumulate over indptr/indices per subset.
 

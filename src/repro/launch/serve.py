@@ -215,7 +215,9 @@ def serve_gcn_sessions(arch: str, *, reduced: bool = True, slots: int = 4,
     clips; default is the NTU 25-joint skeleton.
 
     The adaptive-streaming knobs: ``use_ck`` (``--ck``) serves with the
-    windowed data-dependent C_k graph (``repro.core.agcn.adaptive``) and
+    windowed data-dependent C_k graph (``repro.core.agcn.adaptive``; the
+    published whole-clip form, ``ck_form="clip"``, has no stream to run
+    on and is refused) and
     ``saliency_thresh`` (``--saliency-thresh``) > 0 skips uninformative
     frames per session through a :class:`~repro.serving.saliency.
     SaliencyGate` — both tag the merged rows (``ck``/``saliency`` axes)

@@ -129,7 +129,10 @@ class GcnService:
     and elastic tier migration all happen between steps on the host.
 
     Parameters:
-      cfg              — a gcn-family ``ModelConfig``.
+      cfg              — a gcn-family ``ModelConfig``; with ``use_ck``,
+                         its ``ck_form`` must be ``"window"`` (the
+                         published whole-clip C_k needs a whole clip,
+                         which a live session does not have).
       backend          — engine backend (``reference`` | ``pallas``).
       qos              — scheduler policy (``fifo`` | ``preempt`` |
                          ``deadline``).
@@ -262,6 +265,8 @@ class GcnService:
 
         if qos not in QOS_POLICIES:
             raise ValueError(f"unknown QoS policy {qos!r}")
+        if cfg.use_ck and cfg.ck_form == "clip":
+            raise ValueError(engine.STREAMING_CK_REFUSAL)
         if policy not in CONTROL_POLICIES:
             raise ValueError(f"unknown capacity policy {policy!r} "
                              f"(expected one of {CONTROL_POLICIES})")
@@ -1492,7 +1497,9 @@ def run_sessions(
     (``ntu50``, ``hand21``, ...) — clips are generated at that skeleton's
     joint count (None = the default ``ntu25``).  ``use_ck`` switches the
     model to the windowed data-dependent C_k graph
-    (``repro.core.agcn.adaptive``) and ``saliency_thresh`` > 0 gates
+    (``repro.core.agcn.adaptive``; a config whose ``ck_form`` is the
+    published whole-clip ``"clip"`` is refused, as by
+    :class:`GcnService`) and ``saliency_thresh`` > 0 gates
     uninformative frames (``repro.serving.saliency``) — the two
     adaptive-streaming knobs, tagged onto the row only when on.  Returns
     the :meth:`GcnService.metrics` dict (also the row merged into

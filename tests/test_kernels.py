@@ -74,6 +74,72 @@ def test_graph_sconv_matches_ref(R, V, Ci, Co, K):
                                atol=1e-3, rtol=1e-3)
 
 
+@pytest.mark.parametrize("N,T,V,Ci,Co,K", [
+    (2, 16, 25, 8, 16, 3),
+    (3, 75, 25, 6, 8, 3),         # T with odd divisors only: tile 75 or 25
+    (2, 12, 25, 130, 256, 3),     # two Cout tiles; a VMEM-capped row tile
+    (1, 7, 21, 4, 8, 2),          # prime T, a narrower skeleton
+])
+def test_graph_sconv_rows_matches_ref(N, T, V, Ci, Co, K):
+    """Per-sample graphs: every row of sample n takes g[n], also when a
+    row tile is smaller than a sample."""
+    ks = jax.random.split(jax.random.PRNGKey(T + Ci), 3)
+    x = jax.random.normal(ks[0], (N, T, V, Ci))
+    g = jax.random.normal(ks[1], (N, K, V, V))
+    w = jax.random.normal(ks[2], (K, Ci, Co))
+    out = ops.graph_sconv_rows(x, g, w, interpret=ops.interpret_mode())
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref.graph_sconv_rows_ref(x, g, w)),
+                               atol=1e-3, rtol=1e-3)
+
+
+@pytest.mark.parametrize("S,V,E,valid,scale", [
+    (3, 25, 4800, 25, 1.0 / 4800),
+    (4, 25, 200, 20, 0.05),
+    (2, 8, 128, 8, None),
+])
+def test_similarity_k1_scaled_matches_jnp(S, V, E, valid, scale):
+    """The similarity kernel with K = 1 and a stated scale: a masked
+    softmax over the columns of scale·Θ·Φᵀ."""
+    from repro.kernels.window_sim import windowed_similarity_pallas
+
+    ks = jax.random.split(jax.random.PRNGKey(E + S), 2)
+    vp = -(-V // 8) * 8
+    th = jax.random.normal(ks[0], (S, 1, vp, E))
+    ph = jax.random.normal(ks[1], (S, 1, vp, E))
+    out = windowed_similarity_pallas(th, ph, valid, scale=scale,
+                                     name="ck_sim",
+                                     interpret=ops.interpret_mode())
+    sc = E ** -0.5 if scale is None else scale
+    logits = jnp.einsum("sie,sje->sij", th[:, 0], ph[:, 0]) * sc
+    logits = jnp.where(jnp.arange(vp) < valid, logits, -1e30)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(jax.nn.softmax(logits, -1)),
+                               atol=1e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("N,T,V,C,Ce,K,valid", [
+    (2, 16, 25, 6, 2, 3, 0),
+    (1, 40, 25, 16, 8, 3, 22),    # padded input joints masked
+    (3, 9, 21, 5, 4, 2, 0),
+])
+def test_clip_similarity_matches_adaptive(N, T, V, C, Ce, K, valid):
+    """ops.clip_similarity (ck_proj + ck_sim) against its jnp twin
+    adaptive.clip_ck."""
+    from repro.core.agcn import adaptive
+
+    ks = jax.random.split(jax.random.PRNGKey(N * T + C), 3)
+    x = jax.random.normal(ks[0], (N, T, V, C))
+    w = 2.0 * jax.random.normal(ks[1], (C, 2 * K * Ce)) / np.sqrt(C)
+    b = jax.random.normal(ks[2], (2 * K * Ce,))
+    out = ops.clip_similarity(x, w, b, K, valid,
+                              interpret=ops.interpret_mode())
+    want = adaptive.clip_ck(x, w, b, K, valid)
+    assert out.shape == (N, K, V, V)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-5, rtol=1e-4)
+
+
 @pytest.mark.parametrize("B,S,Hkv,G,D,valid", [
     (1, 512, 2, 4, 32, 512),
     (2, 1024, 4, 3, 64, 700),

@@ -667,3 +667,23 @@ def test_overload_demand_queues_slo_sheds(params, prune_plan):
     assert sum(svc_s.poll(h).state == "rejected"
                for h, p in hs) == ms["sessions_rejected"]
     assert all(svc_d.poll(h).state == "done" for h, _ in hd)
+
+
+def test_streaming_refuses_published_ck():
+    """The published C_k pools over the whole clip, which a live session
+    does not have: the slab, GcnService and ``serve sessions --ck``
+    (``run_sessions``) refuse ck_form='clip' and say why."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_config("agcn-2s", reduced=True),
+                              use_ck=True, ck_form="clip")
+    why = "no whole clip"
+    with pytest.raises(ValueError, match=why):
+        GcnService(cfg, capacity_tiers=(2,))
+    with pytest.raises(ValueError, match=why):
+        serving.run_sessions(cfg, slots=2, n_sessions=2, use_ck=True)
+    plan = engine.build_execution_plan(
+        M.init_params(cfg, jax.random.PRNGKey(0)), cfg, None)
+    x = jnp.zeros((2, cfg.gcn_frames, cfg.gcn_joints, cfg.gcn_in_channels))
+    with pytest.raises(ValueError, match=why):
+        engine.init_session_slab(plan, 2, x_calib=x)

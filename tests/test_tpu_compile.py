@@ -131,3 +131,37 @@ def test_window_sim_compiles(one_chip, V, ce):
                                              interpret=False),
         ((slots, 9, V, ce), jnp.float32), ((slots, 9, V, ce), jnp.float32))
     assert ops.kernel_counts(text) == {"window_sim": 1}
+
+
+@pytest.mark.parametrize("T,cin,cout", [
+    (300, 3, 64),         # block 0
+    (300, 64, 64),
+    (150, 128, 128),
+    (75, 256, 256),
+])
+def test_graph_sconv_rows_compiles(one_chip, T, cin, cout):
+    V, vp = 25, 32
+    text = _compiled_text(
+        one_chip,
+        lambda x, g, w: ops.graph_sconv_rows(x, g, w, interpret=False),
+        ((N, T, V, cin), jnp.float32), ((N, 3, vp, vp), jnp.float32),
+        ((3, cin, cout), jnp.float32))
+    assert ops.kernel_counts(text) == {"graph_sconv_rows": 1}
+
+
+@pytest.mark.parametrize("T,cin,cout", [
+    (300, 3, 64),         # Ce·T = 4800 at every published block
+    (300, 64, 64),
+    (150, 128, 128),
+    (75, 256, 256),
+    (300, 64, 128),       # Ce·T = 9600
+])
+def test_clip_similarity_compiles(one_chip, T, cin, cout):
+    ce = cout // 4
+    text = _compiled_text(
+        one_chip,
+        lambda x, w, b: ops.clip_similarity(x, w, b, 3, interpret=False),
+        ((N, T, 25, cin), jnp.float32), ((cin, 6 * ce), jnp.float32),
+        ((6 * ce,), jnp.float32))
+    assert ops.kernel_counts(text) == {"ck_proj": 1, "ck_sim": 1}
+
