@@ -20,8 +20,7 @@ from repro.kernels.cavity_tconv import (B_TILE, cavity_tconv_pallas,
 from repro.kernels.graph_sconv import (CO_TILE, graph_sconv_csr_pallas,
                                        graph_sconv_pallas,
                                        graph_sconv_rows_pallas, row_tile)
-from repro.kernels.rfc_pack import (ROW_TILE, rfc_decode_pallas,
-                                    rfc_encode_pallas)
+from repro.kernels.rfc_pack import rfc_decode_pallas, rfc_encode_pallas
 from repro.kernels.window_sim import (ck_projection_pallas, proj_row_tile,
                                       windowed_similarity_pallas)
 
@@ -72,21 +71,19 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
 # RFC
 # ---------------------------------------------------------------------------
 
-def _pad_rfc(x: jnp.ndarray, bank: int) -> jnp.ndarray:
-    """(..., C) -> (rows, C') with C' a bank multiple and rows whole tiles."""
-    flat = _pad_to(x.reshape(-1, x.shape[-1]), 1, bank)
-    return _pad_to(flat, 0, ROW_TILE if flat.shape[0] > ROW_TILE else 8)
+def _rfc_rows(x: jnp.ndarray, bank: int) -> jnp.ndarray:
+    """(..., C) -> (rows, C') with C' a bank multiple; the kernels take
+    any row count."""
+    return _pad_to(x.reshape(-1, x.shape[-1]), 1, bank)
 
 
 def rfc_encode(x: jnp.ndarray, bank: int = 16, *, interpret: bool):
     """Encode activations of any (..., C) shape; returns (values, hot)."""
     shape = x.shape
-    rows = int(np.prod(shape[:-1]))
-    vals, hot = rfc_encode_pallas(_pad_rfc(x, bank), bank=bank,
+    vals, hot = rfc_encode_pallas(_rfc_rows(x, bank), bank=bank,
                                   interpret=interpret)
-    vals = vals[:rows, : shape[-1]].reshape(shape)
-    hot = hot[:rows, : shape[-1]].reshape(shape)
-    return vals, hot
+    return (vals[:, : shape[-1]].reshape(shape),
+            hot[:, : shape[-1]].reshape(shape))
 
 
 def rfc_decode(values: jnp.ndarray, hot: jnp.ndarray, bank: int = 16, *,
@@ -95,9 +92,9 @@ def rfc_decode(values: jnp.ndarray, hot: jnp.ndarray, bank: int = 16, *,
     values back to their hot positions.  Any (..., C) shape; lossless on
     post-ReLU activations (the roundtrip contract in test_rfc_format)."""
     shape = values.shape
-    out = rfc_decode_pallas(_pad_rfc(values, bank), _pad_rfc(hot, bank),
+    out = rfc_decode_pallas(_rfc_rows(values, bank), _rfc_rows(hot, bank),
                             bank=bank, interpret=interpret)
-    return out[: int(np.prod(shape[:-1])), : shape[-1]].reshape(shape)
+    return out[:, : shape[-1]].reshape(shape)
 
 
 # ---------------------------------------------------------------------------

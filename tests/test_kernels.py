@@ -9,24 +9,51 @@ from repro.core.pruning.cavity import cavity_pattern, tile_pattern
 from repro.kernels import ops, ref
 
 
-@pytest.mark.parametrize("rows,cols", [(8, 16), (32, 64), (100, 48), (7, 160)])
+def _rfc_input(rows, cols, dtype, bank=16):
+    """Seeded normal rows, the last five replaced by edge rows: all zero,
+    all hot, one hot lane at in-bank index 15 (the longest move),
+    alternating hot and cold, and hot only in the last bank."""
+    x = np.array(jax.random.normal(jax.random.PRNGKey(rows * cols),
+                                   (rows, cols)), np.float32)
+    lane = np.arange(cols) % bank
+    mag = np.abs(x[-5:]) + 0.5
+    x[-5] = 0.0
+    x[-4] = mag[1]
+    x[-3] = np.where(lane == bank - 1, mag[2], -mag[2])
+    x[-2] = np.where(lane % 2 == 0, mag[3], -mag[3])
+    x[-1] = np.where(np.arange(cols) >= cols - bank, mag[4], -mag[4])
+    return jnp.asarray(x, dtype)
+
+
+# ragged row counts (none a multiple of the 256-row tile) at the model's
+# channel widths, besides small and odd shapes
+RFC_SHAPES = [(8, 16), (32, 64), (100, 48), (7, 160),
+              (1000, 64), (1600, 128), (30400, 256), (1000, 256)]
+
+
+@pytest.mark.parametrize("rows,cols", RFC_SHAPES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_rfc_encode_matches_ref(rows, cols, dtype):
-    x = jax.random.normal(jax.random.PRNGKey(rows * cols), (rows, cols), dtype)
+    x = _rfc_input(rows, cols, dtype)
     v_k, h_k = ops.rfc_encode(x, interpret=ops.interpret_mode())
     v_r, h_r = ref.rfc_encode_ref(x.astype(jnp.float32))
-    np.testing.assert_allclose(
-        np.asarray(v_k, np.float32), np.asarray(v_r), atol=1e-2)
-    np.testing.assert_array_equal(np.asarray(h_k) > 0, np.asarray(h_r) > 0)
+    assert v_k.dtype == h_k.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(v_k, np.float32),
+                                  np.asarray(v_r))
+    np.testing.assert_array_equal(np.asarray(h_k, np.float32),
+                                  np.asarray(h_r))
 
 
-@pytest.mark.parametrize("rows,cols", [(8, 16), (32, 64), (100, 48)])
+@pytest.mark.parametrize("rows,cols", RFC_SHAPES[:3] + RFC_SHAPES[4:7])
 def test_rfc_roundtrip(rows, cols):
-    x = jax.random.normal(jax.random.PRNGKey(1), (rows, cols))
-    v, h = ops.rfc_encode(x, interpret=ops.interpret_mode())
-    out = ops.rfc_decode(v, h, interpret=ops.interpret_mode())
-    np.testing.assert_allclose(np.asarray(out), np.maximum(np.asarray(x), 0),
-                               atol=1e-6)
+    for dtype in (jnp.float32, jnp.bfloat16):
+        x = _rfc_input(rows, cols, dtype)
+        v, h = ops.rfc_encode(x, interpret=ops.interpret_mode())
+        out = ops.rfc_decode(v, h, interpret=ops.interpret_mode())
+        assert out.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.maximum(np.asarray(x, np.float32),
+                                                 0))
 
 
 def test_rfc_multidim():

@@ -12,6 +12,8 @@ is imported: only one process may load the TPU compiler library, and with
 several test workers the others must collect the same tests and never
 touch it.  Keep these tests in this one file.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -112,14 +114,20 @@ def test_cavity_tconv_step_compiles(one_chip, V, C, F):
     assert ops.kernel_counts(text) == {"cavity_tconv_step": 1}
 
 
-@pytest.mark.parametrize("V,C", [(25, 64), (25, 128), (50, 256)])
+@pytest.mark.parametrize("V,C", [
+    (25, 64), (25, 128), (50, 256),
+    (200, 64), (200, 256),    # clip-sized: 2·300·200 = 120 000 rows, not a
+])                            # multiple of the 256-row tile
 def test_rfc_roundtrip_compiles(one_chip, V, C):
     def roundtrip(h):
         vals, hot = ops.rfc_encode(h, interpret=False)
         return ops.rfc_decode(vals, hot, interpret=False)
 
-    text = _compiled_text(one_chip, roundtrip, ((N, T, V, C), jnp.float32))
-    assert ops.kernel_counts(text) == {"rfc_encode": 1, "rfc_decode": 1}
+    for dtype in (jnp.float32, jnp.bfloat16):
+        text = _compiled_text(one_chip, roundtrip, ((N, T, V, C), dtype))
+        assert ops.kernel_counts(text) == {"rfc_encode": 1, "rfc_decode": 1}
+        # ragged row tiles: no padding to whole tiles, no slicing back
+        assert not re.search(r"\b(pad|slice|dynamic-slice)\(", text)
 
 
 @pytest.mark.parametrize("V,ce", [(25, 16), (50, 64)])
