@@ -332,7 +332,6 @@ def run_routed_sessions(
     deadline_slack: int = 25,
     capacity_tiers: Optional[Sequence[int]] = None,
     load: str = "poisson",
-    fused: bool = True,
     rebalance_every: int = 16,
 ) -> Dict:
     """Serve a generated session load through a :class:`ReplicaRouter` —
@@ -347,7 +346,7 @@ def run_routed_sessions(
     tiers = tuple(capacity_tiers) if capacity_tiers else (slots,)
     router = ReplicaRouter.build(
         cfg, replicas=replicas, backend=backend, qos=qos,
-        capacity_tiers=tiers, quant=quant, seed=seed, fused=fused)
+        capacity_tiers=tiers, quant=quant, seed=seed)
     svc0 = router.services[0]
 
     if lengths is None:

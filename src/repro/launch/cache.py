@@ -1,6 +1,6 @@
 """Where JAX keeps its persistent compilation cache.
 
-Entry points (``chip_smoke.py``, ``repro.launch.serve``, ``benchmarks.run``)
+Entry points (``chip_smoke.py``, ``repro.launch.serve``)
 call :func:`use_compile_cache` before they compile anything.  A
 ``JAX_COMPILATION_CACHE_DIR`` set in the environment wins — JAX reads it
 itself and nothing here sets another directory.  Otherwise the cache lives

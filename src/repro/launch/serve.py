@@ -40,9 +40,7 @@ Modes:
                   serve lm --arch smollm-360m --reduced --prompt-len 16 --gen 32
 
 ``--batch 0`` (the default everywhere) resolves through
-``ModelConfig.serve_batch`` — the one place family/mode defaults live.
-The pre-PR-5 flag spelling (``serve --arch ... [--stream|--sessions S]``)
-still parses, with a deprecation note."""
+``ModelConfig.serve_batch`` — the one place family/mode defaults live."""
 from __future__ import annotations
 
 import argparse
@@ -323,9 +321,6 @@ def generate(arch: str, *, reduced: bool = True, batch: int = 4,
 # CLI
 # ---------------------------------------------------------------------------
 
-SUBCOMMANDS = ("clip", "stream", "sessions", "lm")
-
-
 def _parse_tiers(spec: str):
     """``"2,4,8"`` -> (2, 4, 8); empty/None -> None (fixed capacity)."""
     if not spec:
@@ -461,41 +456,6 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def _legacy_argv(argv):
-    """Map the pre-subcommand flag spelling onto the new CLI.
-
-    ``--sessions S`` -> ``sessions --slots S``, ``--stream`` ->
-    ``stream``, a gcn arch without either -> ``clip``, LM arches ->
-    ``lm``.  Prints a one-line deprecation note naming the new form."""
-    legacy = argparse.ArgumentParser(add_help=False)
-    legacy.add_argument("--arch", required=True)
-    legacy.add_argument("--reduced", action="store_true")
-    legacy.add_argument("--stream", action="store_true")
-    legacy.add_argument("--sessions", type=int, default=0)
-    known, _ = legacy.parse_known_args(argv)
-    cfg = get_config(known.arch, reduced=known.reduced)
-    out = list(argv)
-    if cfg.family != "gcn":
-        mode = "lm"
-    elif known.sessions:
-        mode = "sessions"
-        for i, a in enumerate(out):
-            if a == "--sessions":
-                out[i] = "--slots"
-                break
-            if a.startswith("--sessions="):
-                out[i] = "--slots=" + a.split("=", 1)[1]
-                break
-    elif known.stream:
-        mode = "stream"
-        out.remove("--stream")
-    else:
-        mode = "clip"
-    print(f"# note: flag-style invocation is deprecated — use "
-          f"`serve {mode} ...` (mapped automatically)", file=sys.stderr)
-    return [mode] + out
-
-
 def _print_sessions(results) -> None:
     for r in results:
         cap = (f" capacity={r['capacity']}" if r["capacity"] != "fixed"
@@ -566,33 +526,14 @@ def _print_sessions(results) -> None:
 
 
 def main(argv=None):
-    """CLI entry: subcommand form, with the legacy flag form mapped."""
+    """CLI entry: ``serve clip|stream|sessions|lm [flags]``."""
     from repro.core.agcn.engine import BACKENDS
     from repro.launch.cache import use_compile_cache
 
     use_compile_cache()
 
     argv = list(sys.argv[1:] if argv is None else argv)
-    legacy = False
-    if not argv or argv[0] not in SUBCOMMANDS:
-        # the legacy flag spelling is recognized by its required --arch;
-        # map it first so `serve --arch ... --help` reaches the right
-        # subcommand's help instead of an 'invalid choice' error
-        if any(a == "--arch" or a.startswith("--arch=") for a in argv):
-            argv = _legacy_argv(argv)
-            legacy = True
-        else:
-            build_parser().parse_args(argv or ["-h"])
-            return
-    if legacy:
-        # the old single parser accepted every flag in every mode (extras
-        # were ignored); keep that contract for mapped invocations
-        args, extra = build_parser().parse_known_args(argv)
-        if extra:
-            print(f"# note: ignoring legacy flags not used by "
-                  f"`serve {argv[0]}`: {' '.join(extra)}", file=sys.stderr)
-    else:
-        args = build_parser().parse_args(argv)
+    args = build_parser().parse_args(argv or ["-h"])
     cfg = get_config(args.arch, reduced=args.reduced)
     backends = BACKENDS if args.backend == "both" else (args.backend,)
 

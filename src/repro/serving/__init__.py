@@ -24,10 +24,7 @@ Public surface (snapshotted in ``docs/api_surface.txt`` and gated by
 * :class:`Trace`, :func:`replay` — the deterministic trace-replay
   harness: feed a recorded trace byte-identically into any service
   configuration (the ``serve sessions --trace`` / golden-test path).
-* :func:`write_bench`, :func:`bench_key` — BENCH_sessions.json row merge.
-
-The legacy import path ``repro.launch.sessions`` is a deprecation shim
-over this package."""
+* :func:`write_bench`, :func:`bench_key` — BENCH_sessions.json row merge."""
 from repro.serving.capacity import (CapacityConfig, CapacityManager,
                                     ResizeEvent)
 from repro.serving.scheduler import (DEFAULT_BENCH_PATH, QOS_POLICIES,

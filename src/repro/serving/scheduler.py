@@ -1,8 +1,7 @@
 """Session-slab scheduling internals behind the :class:`GcnService` facade.
 
-This module is the host-side half of multi-session stream serving (moved
-here from ``repro.launch.sessions`` — that path is now a deprecation
-shim).  The streaming engine serves *one* lockstep batch of streams; live
+This module is the host-side half of multi-session stream serving.  The
+streaming engine serves *one* lockstep batch of streams; live
 traffic is many independent skeleton sessions arriving and ending at
 different times — the continual-inference regime of CoST-GCN (Hedegaard et
 al., 2022) at the throughput target of the ROADMAP:
@@ -48,7 +47,6 @@ import dataclasses
 import heapq
 import json
 import os
-import warnings
 from collections import deque
 from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
@@ -453,19 +451,6 @@ class TickPlan:
     snap_order: Optional[np.ndarray] = None
     rest_order: Optional[np.ndarray] = None
 
-    def __iter__(self):
-        """Deprecated back-compat unpacking: ``frames, valid, reset =
-        tick_inputs()`` — silently drops the ``hold`` mask (and the
-        snapshot/restore orders), so drivers must migrate to the named
-        fields."""
-        warnings.warn(
-            "unpacking TickPlan as a (frames, valid, reset) 3-tuple is "
-            "deprecated: it drops the hold mask and the snapshot/restore "
-            "orders — use the named fields (.frames/.valid/.reset/.hold/"
-            ".snapshot/.restore)",
-            DeprecationWarning, stacklevel=2)
-        return iter((self.frames, self.valid, self.reset))
-
 
 class SlabScheduler:
     """Slot table + priority admission queue driving ``engine.step_frames``.
@@ -542,8 +527,8 @@ class SlabScheduler:
         # per-tick event budget: the fused tick's order buffers are padded
         # to this static width, and the QoS loops below never schedule more
         # snapshot (or restore) events per tick than it — surplus work
-        # defers to later ticks.  Applied under every policy so the fused
-        # and legacy drivers see identical TickPlans.
+        # defers to later ticks.  Applied under every policy, so every
+        # driver sees the same TickPlans with or without a ring.
         self.max_events = max_events_for(slots)
         # optional host-side allocator for the on-device snapshot ring
         # (``engine.init_snapshot_ring``): rows are S-independent, so one
@@ -816,7 +801,7 @@ class SlabScheduler:
         restores it exactly like a local preemption resume."""
         if self.snap_ring is None:
             raise RuntimeError("scheduler was built without a snapshot "
-                               "ring (fused path only)")
+                               "ring (snap_ring=None)")
         if not self._ring_free:
             raise RuntimeError(
                 f"snapshot ring exhausted ({self.snap_ring} rows, "
@@ -934,8 +919,7 @@ def write_bench(results: List[Dict], path: str = DEFAULT_BENCH_PATH) -> None:
     axis existed: an existing row with the same key is replaced in place,
     every other row survives, and new keys are appended — so
     ``serve sessions --backend pallas`` refreshes only the pallas rows
-    instead of clobbering the reference rows the README tables are
-    rendered from (``tools/bench_tables.py``)."""
+    instead of clobbering the reference rows."""
     existing: List[Dict] = []
     if os.path.exists(path):
         try:

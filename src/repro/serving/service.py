@@ -168,23 +168,12 @@ class GcnService:
                          from ``x_calib`` (or a synthetic pipeline batch)
                          when None.
       x_calib          — (N, T, V, C) calibration clip batch.
-      warm             — pre-compile the slab step for every tier (and the
-                         preempt gather/scatter) at construction so no
+      warm             — pre-compile the tick (plain step and event
+                         tick) for every tier at construction so no
                          session ever pays compile latency.
-      fused            — serve each tick as **one** device dispatch with
-                         async logit readback.  Ticks carrying snapshot or
-                         restore events run ``engine.fused_tick`` (gathers,
-                         scatters, hold/reset masking and the slab step in
-                         a single donated-slab jit, snapshots in an
-                         on-device ring); event-free ticks run the plain
-                         slab step — still one dispatch, no ring plumbing.
-                         False restores the legacy multi-dispatch tick (one
-                         jit per snapshot/restore event + a synchronous
-                         readback) — kept for A/B parity tests and the
-                         throughput benchmark baseline.
-      snap_capacity    — snapshot-ring rows (fused path only): live
-                         preempted sessions a tick can hold device state
-                         for; defaults to ``2 * max(capacity_tiers)``.
+      snap_capacity    — snapshot-ring rows: live preempted sessions a
+                         tick can hold device state for; defaults to
+                         ``2 * max(capacity_tiers)``.
       topologies       — skeleton graphs this service serves (registry
                          names, see ``repro.core.agcn.graph``).  The first
                          entry is the *primary* topology (what
@@ -246,7 +235,7 @@ class GcnService:
                  plans: Optional[Tuple] = None,
                  bn_stats: Optional[Any] = None,
                  x_calib: Optional[np.ndarray] = None,
-                 warm: bool = True, fused: bool = True,
+                 warm: bool = True,
                  snap_capacity: Optional[int] = None,
                  topologies: Sequence[str] = ("ntu25",),
                  sconv: str = "auto", csr_eps: float = 0.0,
@@ -438,7 +427,6 @@ class GcnService:
                            for s in self._tier_slabs[tiers[0]])
 
         # --- scheduler + capacity manager ---------------------------------
-        self.fused = bool(fused)
         self.snap_capacity = int(snap_capacity if snap_capacity is not None
                                  else 2 * max(tiers))
         self.saliency: Optional[SaliencyGate] = None
@@ -450,7 +438,7 @@ class GcnService:
             flush_frames=self.flush_frames,
             first_logit_delay=engine.stream_first_logit_delay(self.plans[0]),
             policy=qos,
-            snap_ring=self.snap_capacity if self.fused else None,
+            snap_ring=self.snap_capacity,
             retain=self.retain_records,
             saliency=self.saliency)
         # deadline drops retire through the same bounded window as
@@ -494,8 +482,8 @@ class GcnService:
             migrate_out = self._slab_shardings[0]
         self._step = jax.jit(on_packed_constants(make_gcn_slab_step(cfg)),
                              out_shardings=step_out)
+        # gathers an active slot's state for export_session
         self._snap_fn = jax.jit(engine.snapshot_slots)
-        self._rest_fn = jax.jit(engine.restore_slots)
         # the one-dispatch tick: slab and snapshot-ring pytrees are
         # DONATED (argnums 1 and 8) — XLA updates them in place and the
         # Python-side inputs die at the call; tick() must only ever pass
@@ -505,18 +493,16 @@ class GcnService:
         self._fused_tick = jax.jit(
             on_packed_constants(make_gcn_fused_tick(cfg)),
             donate_argnums=(1, 8), out_shardings=fused_out)
-        # per-stream on-device snapshot rings (fused path): ring rows are
-        # slot-shaped (S-independent), so one ring serves every capacity
-        # tier and rides through elastic migrations untouched
-        self._rings: Optional[Tuple] = None
-        if self.fused:
+        # per-stream on-device snapshot rings: ring rows are slot-shaped
+        # (S-independent), so one ring serves every capacity tier and
+        # rides through elastic migrations untouched
+        self._rings = tuple(
+            engine.init_snapshot_ring(s, self.snap_capacity)
+            for s in self._tier_slabs[tiers[0]])
+        if mesh is not None:
             self._rings = tuple(
-                engine.init_snapshot_ring(s, self.snap_capacity)
-                for s in self._tier_slabs[tiers[0]])
-            if mesh is not None:
-                self._rings = tuple(
-                    jax.device_put(r, sh)
-                    for r, sh in zip(self._rings, self._ring_sharding))
+                jax.device_put(r, sh)
+                for r, sh in zip(self._rings, self._ring_sharding))
         # the tier-migration pair fused into one jit: gather rows out of
         # the source slab, scatter into the (pristine) target slab
         self._migrate_fn = jax.jit(
@@ -530,14 +516,11 @@ class GcnService:
             self._fused_tick = self._under_mesh(self._fused_tick)
             self._migrate_fn = self._under_mesh(self._migrate_fn)
             self._snap_fn = self._under_mesh(self._snap_fn)
-            self._rest_fn = self._under_mesh(self._rest_fn)
 
         # --- session bookkeeping -------------------------------------------
         self._next_sid = 0
         self._sessions: Dict[int, SessionRequest] = {}
         self._records: Dict[int, SessionRecord] = {}
-        self._snaps: Dict[int, Tuple] = {}    # sid -> per-stream snapshots
-                                              # (legacy tick path only)
         # retirement window: finished/missed sids in order; once more than
         # retain_records sessions have retired after one, its request/
         # record entries are dropped (lifetime totals live in the
@@ -587,14 +570,13 @@ class GcnService:
     def _retire(self, sid: int) -> None:
         """Enter ``sid`` into the bounded retirement window; the oldest
         retiree beyond ``retain_records`` loses its host-side bookkeeping
-        (request, record, legacy snapshot, missed/rejected-sid mirrors) —
+        (request, record, missed/rejected-sid mirrors) —
         its outcome already lives in the lifetime aggregates."""
         self._retired.append(sid)
         while len(self._retired) > self.retain_records:
             old = self._retired.popleft()
             self._sessions.pop(old, None)
             self._records.pop(old, None)
-            self._snaps.pop(old, None)
             self.sched.missed_sids.discard(old)
             self._rejected.discard(old)
 
@@ -606,8 +588,8 @@ class GcnService:
             self._missed_tick.append(req.sid)
 
     def _warm(self) -> None:
-        """Compile the active tick path for every tier (plus the preempt
-        gather/scatter pair on the legacy path) before traffic arrives —
+        """Compile the tick for every tier — the plain slab step of each
+        skeleton group and the event tick — before traffic arrives:
         post-warmup, no admission/hold/occupancy/event-count combination
         retraces within a tier."""
         jnp, jax = self._jnp, self._jax
@@ -616,8 +598,7 @@ class GcnService:
         for S, slabs in self._tier_slabs.items():
             zf = jnp.zeros((S, V, C))
             zb = jnp.zeros((S,), bool)
-            # the no-event tick (fused and legacy paths alike) is the
-            # plain slab step
+            # the no-event tick is the plain slab step
             _, wl = self._step(self._consts[self.primary], slabs, zf, zb,
                                zb, zb)
             jax.block_until_ready(wl)
@@ -626,35 +607,25 @@ class GcnService:
             for t in self.topologies[1:]:
                 _, wl = self._step(self._consts[t], slabs, zf, zb, zb, zb)
                 jax.block_until_ready(wl)
-            if self.fused:
-                # the fused event tick donates its slab/ring arguments, so
-                # warm it on throwaway copies — never on the pristine tier
-                # slabs or the live ring.  One trace per tier covers any
-                # event count: the order buffers are traced values of the
-                # static (max_events_for(S), 2) shape.
-                wslabs = tuple(jax.tree_util.tree_map(jnp.copy, s)
-                               for s in slabs)
-                wrings = tuple(engine.init_snapshot_ring(
-                    s, self.snap_capacity) for s in slabs)
-                if self.mesh is not None:
-                    # match the live rings' placement so warmup compiles
-                    # the same input signature traffic will use
-                    wrings = tuple(
-                        jax.device_put(r, sh)
-                        for r, sh in zip(wrings, self._ring_sharding))
-                zo = jnp.asarray(pad_event_orders([], max_events_for(S)))
-                out = self._fused_tick(self._consts[self.primary], wslabs,
-                                       zf, zb, zb, zb, zo, zo, wrings)
-                jax.block_until_ready(out[1])
-        if self.qos == "preempt" and not self.fused:
-            # the legacy preempt gather/scatter traces per tier shape —
-            # warm it at every tier so the first preemption after a grow
-            # is free (the fused path carries its events in-dispatch)
-            for slabs in self._tier_slabs.values():
-                w = tuple(self._snap_fn(s, jnp.asarray(0)) for s in slabs)
-                ws = tuple(self._rest_fn(s, jnp.asarray(0), x)
-                           for s, x in zip(slabs, w))
-                jax.block_until_ready(ws)
+            # the event tick donates its slab/ring arguments, so warm it
+            # on throwaway copies — never on the pristine tier slabs or
+            # the live ring.  One trace per tier covers any event count:
+            # the order buffers are traced values of the static
+            # (max_events_for(S), 2) shape.
+            wslabs = tuple(jax.tree_util.tree_map(jnp.copy, s)
+                           for s in slabs)
+            wrings = tuple(engine.init_snapshot_ring(
+                s, self.snap_capacity) for s in slabs)
+            if self.mesh is not None:
+                # match the live rings' placement so warmup compiles
+                # the same input signature traffic will use
+                wrings = tuple(
+                    jax.device_put(r, sh)
+                    for r, sh in zip(wrings, self._ring_sharding))
+            zo = jnp.asarray(pad_event_orders([], max_events_for(S)))
+            out = self._fused_tick(self._consts[self.primary], wslabs,
+                                   zf, zb, zb, zb, zo, zo, wrings)
+            jax.block_until_ready(out[1])
         # every ordered tier pair compiles its fixed-shape migration
         # (min(S_old, S_new) rows regardless of occupancy), so a traffic-
         # time grow/shrink never pays trace latency
@@ -819,8 +790,8 @@ class GcnService:
         For an active/draining session the default returns the logits of
         the most recent *forced* tick — possibly ``None`` right after a
         tick whose async readback is still pending — so a client polling
-        every tick costs no device sync (the fused path's readback
-        overlap survives the polling).  ``wait=True`` forces the pending
+        every tick costs no device sync (the tick's readback overlap
+        survives the polling).  ``wait=True`` forces the pending
         readback first (the wait is the ``readback`` phase),
         guaranteeing the logits reflect the latest tick."""
         req = self._req(h)
@@ -921,7 +892,7 @@ class GcnService:
     def _force_logits(self) -> Optional[np.ndarray]:
         """Force the pending tick's logits to host (no-op once forced).
 
-        The fused tick keeps ``_last_logits`` as a device array — a
+        The tick keeps ``_last_logits`` as a device array — a
         future the host only waits on when someone actually reads it
         (``poll``, a finishing session, ``metrics``).  The block is the
         ``readback`` phase (``wall_device_s``): the host waiting on the
@@ -970,17 +941,17 @@ class GcnService:
     def tick(self) -> List[SessionRecord]:
         """Run one scheduler tick: capacity decision (elastic), QoS policy
         + admissions, snapshot/restore orders, one device dispatch for
-        all slots (the donated fused megakernel on event ticks, the plain
-        slab step on no-event ticks; or the legacy multi-dispatch
-        sequence when ``fused=False``), drain accounting.  Returns the sessions that
-        finished this tick (their records are also kept for
-        :meth:`poll`).
+        all slots (the donated event tick ``engine.fused_tick`` when the
+        tick carries snapshot or restore events, the plain slab step
+        otherwise; plus one per extra skeleton group), drain accounting.
+        Returns the sessions that finished this tick (their records are
+        also kept for :meth:`poll`).
 
-        On the fused path the logits stay on device: the host queues the
-        dispatch and immediately resumes scheduling — the transfer is
-        only forced when a session finishes this tick, someone polls, or
-        metrics are read, so tick *t*'s device work overlaps tick
-        *t+1*'s host-side planning.
+        The logits stay on device: the host queues the dispatch and
+        immediately resumes scheduling — the transfer is only forced
+        when a session finishes this tick, someone polls, or metrics are
+        read, so tick *t*'s device work overlaps tick *t+1*'s host-side
+        planning.
 
         The tick runs as contiguous named phases (``TICK_PHASES``), each
         a ``svc.<phase>`` profiler span timed into ``phase_s``: *feed*
@@ -1054,8 +1025,7 @@ class GcnService:
             # masks: valid/reset only inside the group (reset must be
             # group-masked — step_frames resets *before* the hold
             # select), hold everything outside it.  None =
-            # single-topology service, which takes exactly the legacy
-            # dispatch.
+            # single-topology service: one dispatch.
             groups = (self._topology_groups()
                       if len(self.topologies) > 1 else None)
             valid, reset, hold = tp.valid, tp.reset, tp.hold
@@ -1063,25 +1033,20 @@ class GcnService:
                 mp = groups[0][1]
                 valid, reset, hold = valid & mp, reset & mp, hold | ~mp
             # a session finishing this tick needs its logits row before
-            # drain accounting, so the readback is forced then; the legacy
-            # tick is synchronous and always forces it.  Otherwise the
-            # fused path leaves the future pending.
-            force = not self.fused or any(
+            # drain accounting, so the readback is forced then; otherwise
+            # the future stays pending.
+            force = any(
                 slot is not None and not slot.held
                 and slot.total is not None and slot.rel == slot.total - 1
                 for slot in self.sched.slots)
         with self._phase("stage"):
-            events = self.fused and bool(tp.snapshot or tp.restore)
-            snap_at, rest_at = (), ()
+            events = bool(tp.snapshot or tp.restore)
             frames = jnp.asarray(tp.frames)
             masks = (jnp.asarray(valid), jnp.asarray(reset),
                      jnp.asarray(hold))
             if events:
                 orders = (jnp.asarray(tp.snap_order),
                           jnp.asarray(tp.rest_order))
-            elif not self.fused:
-                snap_at = [(jnp.asarray(s), sid) for s, sid in tp.snapshot]
-                rest_at = [(jnp.asarray(s), sid) for s, sid in tp.restore]
             group_args = [(t, jnp.asarray(tp.valid & m),
                            jnp.asarray(tp.reset & m),
                            jnp.asarray(tp.hold | ~m))
@@ -1098,26 +1063,8 @@ class GcnService:
                     *orders, self._rings)
                 self.call_arrays += self._fused_arrays
             else:
-                # legacy events: capture before restore/step
-                for s, sid in snap_at:
-                    self._snaps[sid] = tuple(self._snap_fn(slab, s)
-                                             for slab in self.slabs)
-                    self.device_dispatches += len(self.slabs)
-                    self.call_arrays += len(self.slabs) + _n_arrays(
-                        (self.slabs, self._snaps[sid]))
-                for s, sid in rest_at:
-                    snaps = self._snaps.pop(sid)
-                    old = self.slabs
-                    self.slabs = tuple(
-                        self._rest_fn(slab, s, sn)
-                        for slab, sn in zip(self.slabs, snaps))
-                    self.device_dispatches += len(self.slabs)
-                    self.call_arrays += len(old) + _n_arrays(
-                        (old, snaps, self.slabs))
-                # no-event fused tick (the common case): the plain slab
-                # step is the same single dispatch minus the ring
-                # plumbing — the fused win here is skipping the per-tick
-                # readback, not the kernel shape
+                # no-event tick (the common case): the plain slab step,
+                # the same single dispatch minus the ring plumbing
                 self.slabs, logits = self._step(
                     self._consts[self.primary], self.slabs, frames, *masks)
                 self.call_arrays += self._step_arrays[self.primary]
@@ -1241,16 +1188,12 @@ class GcnService:
                 raise ValueError(f"session {sid} is in no exportable state")
             if item is not req:
                 # a preempted slot awaiting re-admission: its device state
-                # is a ring row (fused) or a host snapshot tuple (legacy)
-                if self.fused:
-                    row = self.sched.ring_release(sid)
-                    snaps = tuple(
-                        jax.device_get(jax.tree_util.tree_map(
-                            lambda leaf: leaf[row], ring))
-                        for ring in self._rings)
-                else:
-                    snaps = tuple(jax.device_get(sn)
-                                  for sn in self._snaps.pop(sid))
+                # is a snapshot-ring row
+                row = self.sched.ring_release(sid)
+                snaps = tuple(
+                    jax.device_get(jax.tree_util.tree_map(
+                        lambda leaf: leaf[row], ring))
+                    for ring in self._rings)
         self._sessions.pop(sid, None)
         return {"item": item, "snaps": snaps}
 
@@ -1260,10 +1203,9 @@ class GcnService:
         The package's scheduler item re-enters the admission queue under
         a fresh local sid (the handle returned here supersedes the
         origin replica's).  A package carrying device snapshots uploads
-        them first — into a snapshot-ring row (fused) or the host
-        snapshot table (legacy) — so the next admission restores the
-        session exactly like a local preemption resume: same ring
-        phases, same block clocks, same running pool."""
+        them into a snapshot-ring row first, so the next admission
+        restores the session exactly like a local preemption resume:
+        same ring phases, same block clocks, same running pool."""
         jax, jnp = self._jax, self._jnp
         item = package["item"]
         snaps = package["snaps"]
@@ -1277,21 +1219,18 @@ class GcnService:
         req.sid = sid
         self._sessions[sid] = req
         if snaps is not None:
-            if self.fused:
-                row = self.sched.ring_adopt(sid)
+            row = self.sched.ring_adopt(sid)
+            self._rings = tuple(
+                jax.tree_util.tree_map(
+                    lambda r, sv: r.at[row].set(jnp.asarray(sv, r.dtype)),
+                    ring, sn)
+                for ring, sn in zip(self._rings, snaps))
+            if self.mesh is not None:
+                # keep the rings on their replicated mesh placement so
+                # the event tick's compiled signature never changes
                 self._rings = tuple(
-                    jax.tree_util.tree_map(
-                        lambda r, sv: r.at[row].set(jnp.asarray(sv, r.dtype)),
-                        ring, sn)
-                    for ring, sn in zip(self._rings, snaps))
-                if self.mesh is not None:
-                    # keep the rings on their replicated mesh placement so
-                    # the fused tick's compiled signature never changes
-                    self._rings = tuple(
-                        jax.device_put(r, sh)
-                        for r, sh in zip(self._rings, self._ring_sharding))
-            else:
-                self._snaps[sid] = tuple(snaps)
+                    jax.device_put(r, sh)
+                    for r, sh in zip(self._rings, self._ring_sharding))
         self.sched.queue.push(item)
         return SessionHandle(sid=sid)
 
@@ -1376,7 +1315,6 @@ class GcnService:
             "wall_s": wall,
             "wall_host_s": self.wall_host_s,
             "wall_device_s": self.wall_device_s,
-            "tick_path": "fused" if self.fused else "legacy",
             "device_dispatches": self.device_dispatches,
             "call_arrays": self.call_arrays,
             "frames_per_s": sched.valid_frames / wall if wall > 0 else 0.0,
@@ -1414,8 +1352,8 @@ class GcnService:
                         else recs[len(recs) - min(keep_records, len(recs)):]),
         }
         # adaptive-streaming axes ride the row ONLY when enabled, so every
-        # feature-off row (and the tracked legacy BENCH artifacts) stays
-        # byte-identical; bench_key defaults the absent keys to off
+        # feature-off row keeps its shape; bench_key defaults the absent
+        # keys to off
         if getattr(self.cfg, "use_ck", False):
             out["ck"] = True
         if self.saliency is not None:
@@ -1463,7 +1401,6 @@ def run_sessions(
     priorities: Optional[Sequence[int]] = None,
     capacity_tiers: Optional[Sequence[int]] = None,
     load: str = "poisson",
-    fused: bool = True,
     mesh: int = 0,
     policy: str = "demand",
     slo_config: Optional[SloConfig] = None,
@@ -1516,7 +1453,7 @@ def run_sessions(
     svc = GcnService(cfg, backend=backend, qos=qos, capacity_tiers=tiers,
                      policy=policy, slo_config=slo_config,
                      topologies=(topology,) if topology else ("ntu25",),
-                     quant=quant, seed=seed, fused=fused, mesh=mesh_obj,
+                     quant=quant, seed=seed, mesh=mesh_obj,
                      saliency_thresh=saliency_thresh)
 
     if lengths is None:

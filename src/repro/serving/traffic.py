@@ -344,7 +344,6 @@ def replay(
     deadline_slack: int = 25,
     quant: bool = True,
     seed: int = 0,
-    fused: bool = True,
     record_outcomes: bool = False,
     max_ticks: int = 100_000,
     plans=None,
@@ -382,7 +381,7 @@ def replay(
 
     svc = GcnService(cfg, backend=backend, qos=qos, policy=policy,
                      capacity_tiers=tuple(capacity_tiers), quant=quant,
-                     seed=seed, fused=fused, slo_config=slo_config,
+                     seed=seed, slo_config=slo_config,
                      plans=plans, bn_stats=bn_stats,
                      record_outcomes=record_outcomes,
                      saliency_thresh=saliency_thresh)

@@ -1,16 +1,13 @@
-"""The one-dispatch serving tick (engine.fused_tick + GcnService fused path).
+"""The one-dispatch serving tick (engine.fused_tick + GcnService.tick).
 
 The tentpole locks:
 
-* **Fused == legacy, bitwise** — on both backends, a scripted QoS trace
-  (admissions, preemptions with restores, a mid-clip elastic grow/shrink
-  migration) produces byte-identical final logits whether the service
-  runs the fused single-dispatch tick or the legacy multi-dispatch
-  sequence; bystander sessions riding alongside the churn are identical
-  too (every session in the trace is compared).
-* **Single dispatch per tick** — the fused service issues exactly one
-  jitted call per tick regardless of event counts, while the legacy path
-  pays 2 extra dispatches per snapshot/restore event per stream.
+* **Parity with the independent run** — on both backends, a scripted QoS
+  trace (admissions, preemptions with restores, a mid-clip elastic
+  grow/shrink migration) gives every session, bystanders included, the
+  logits of its own uninterrupted single-stream run.
+* **Single dispatch per tick** — the service issues exactly one jitted
+  call per tick regardless of event counts.
 * **One compilation per tier** — snapshot/restore event counts (0, 1,
   max) are traced values of the fixed-shape sentinel-padded order
   buffers, so they never retrace; overflowing the static buffer raises
@@ -95,41 +92,49 @@ def _drive_requests(svc, reqs, max_ticks=600):
     return {rec.sid: rec.logits for rec in m["records"]}, m
 
 
+def _run_independent(plan, bn, clip):
+    """One session alone: batch-1 step_frame over clip + flush drain —
+    the uninterrupted single-stream baseline."""
+    state = engine.init_stream_state(plan, 1, bn_stats=bn)
+    step = jax.jit(engine.step_frame)
+    xc = jnp.asarray(clip)[None]
+    T = xc.shape[1]
+    zeros = jnp.zeros_like(xc[:, 0])
+    logits = None
+    for r in range(T + engine.stream_flush_frames(plan, T)):
+        frame = xc[:, r] if r < T else zeros
+        state, logits = step(plan, state, frame, jnp.asarray(r < T))
+    return np.asarray(logits)[0]
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("backend", ["reference", "pallas"])
-def test_fused_matches_legacy_qos_trace(params, prune_plan, backend):
-    """Fused single-dispatch ticks == legacy multi-dispatch ticks, bitwise,
-    across preemptions + restores + an elastic grow/shrink migration —
-    including every bystander session riding through the churn — and the
-    fused path really is one device dispatch per tick."""
+def test_qos_trace_matches_independent(params, prune_plan, backend):
+    """Through preemptions + restores + an elastic grow/shrink migration,
+    every session of the trace — bystanders riding through the churn
+    included — ends with the logits of its own independent single-stream
+    run, and every tick is one device dispatch."""
     plan, bn = _plan_and_bn(params, prune_plan, backend)
     ccfg = CapacityConfig(tiers=(2, 4), grow_patience=1, shrink_patience=2,
                           cooldown=3)
-    runs = {}
-    for fused in (True, False):
-        svc = GcnService(CFG, backend=backend, plans=(plan,),
-                         bn_stats=(bn,), qos="preempt",
-                         capacity_tiers=(2, 4), capacity_config=ccfg,
-                         fused=fused)
-        runs[fused] = _drive_requests(svc, _qos_trace(np.random.default_rng(7)))
-    of, mf = runs[True]
-    ol, ml = runs[False]
+    svc = GcnService(CFG, backend=backend, plans=(plan,), bn_stats=(bn,),
+                     qos="preempt", capacity_tiers=(2, 4),
+                     capacity_config=ccfg)
+    reqs = _qos_trace(np.random.default_rng(7))
+    got, m = _drive_requests(svc, reqs)
     # the trace actually exercised the churn it scripts
-    assert mf["preemptions"] > 0 and mf["restores"] > 0
-    assert mf["migrations"] > 0
-    assert mf["preemptions"] == ml["preemptions"]
-    assert mf["migrations"] == ml["migrations"]
-    # single dispatch per tick, fused; legacy pays per-event dispatches
-    assert mf["device_dispatches"] == mf["ticks"]
-    assert ml["device_dispatches"] > ml["ticks"]
-    assert mf["tick_path"] == "fused" and ml["tick_path"] == "legacy"
+    assert m["preemptions"] > 0 and m["restores"] > 0
+    assert m["migrations"] > 0
+    # single dispatch per tick, event ticks included
+    assert m["device_dispatches"] == m["ticks"]
     # wall split satellite: both components present and sum to wall_s
-    assert mf["wall_s"] == pytest.approx(
-        mf["wall_host_s"] + mf["wall_device_s"])
-    assert set(of) == set(ol)
-    for sid in sorted(of):
-        np.testing.assert_array_equal(of[sid], ol[sid],
-                                      err_msg=f"session {sid}")
+    assert m["wall_s"] == pytest.approx(m["wall_host_s"] + m["wall_device_s"])
+    # sessions open in arrival order, so the service's sid is the index
+    assert set(got) == set(range(len(reqs)))
+    for i, r in enumerate(sorted(reqs, key=lambda r: r.arrival)):
+        want = _run_independent(plan, bn, r.clip)
+        np.testing.assert_allclose(got[i], want, atol=1e-3, rtol=1e-3,
+                                   err_msg=f"session {i} ({backend})")
 
 
 def test_fused_no_retrace_across_event_counts(params, prune_plan):
@@ -139,7 +144,7 @@ def test_fused_no_retrace_across_event_counts(params, prune_plan):
     of the static sentinel-padded shape — never shape changes."""
     plan, bn = _plan_and_bn(params, prune_plan, "reference")
     svc = GcnService(CFG, plans=(plan,), bn_stats=(bn,), qos="preempt",
-                     capacity_tiers=(2,), warm=False, fused=True)
+                     capacity_tiers=(2,), warm=False)
     from repro.train.steps import (make_gcn_fused_tick, make_gcn_slab_step,
                                    on_packed_constants)
     inner = on_packed_constants(make_gcn_fused_tick(CFG))
@@ -263,8 +268,7 @@ def test_poll_async_default_never_forces_readback(params, prune_plan):
     (or a finishing session) pays the readback, which lands in the
     wall_device_s / device_dispatches accounting."""
     plan, bn = _plan_and_bn(params, prune_plan, "reference")
-    svc = GcnService(CFG, plans=(plan,), bn_stats=(bn,), capacity_tiers=(2,),
-                     fused=True)
+    svc = GcnService(CFG, plans=(plan,), bn_stats=(bn,), capacity_tiers=(2,))
     rng = np.random.default_rng(13)
     h = svc.open_session()
     svc.submit_clip(h, rng.standard_normal((20, V, C)).astype(np.float32))

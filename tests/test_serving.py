@@ -252,10 +252,11 @@ def test_no_retrace_within_tier(params):
     bn = engine.collect_bn_stats(
         plan, jax.random.normal(jax.random.PRNGKey(1),
                                 (2, CFG.gcn_frames, V, C)))
-    # fused=False pins the legacy step path this test wraps; the fused
-    # tick's no-retrace guard lives in tests/test_fused_tick.py
+    # these ticks carry no snapshot/restore events, so every one runs the
+    # plain step wrapped below; the event tick's no-retrace guard lives
+    # in tests/test_fused_tick.py
     svc = GcnService(CFG, plans=(plan,), bn_stats=(bn,), capacity_tiers=(3,),
-                     warm=False, fused=False)
+                     warm=False)
     # count traces of the service's own step by re-jitting a counting
     # wrapper around the same step factory the service uses
     from repro.train.steps import make_gcn_slab_step, on_packed_constants

@@ -470,38 +470,6 @@ def test_write_bench_merges_by_backend_slots_qos(tmp_path):
     assert len(rows) == 1 and rows[0]["frames_per_s"] == 500.0
 
 
-# ------------------------------------------------------------- deprecations
-
-def test_launch_sessions_shim_forwards_and_warns():
-    """The legacy import path (repro.launch.sessions) resolves every moved
-    public name from repro.serving — with a DeprecationWarning — and still
-    raises AttributeError for unknown names."""
-    from repro.launch import sessions as legacy
-    with pytest.warns(DeprecationWarning, match="moved to repro.serving"):
-        assert legacy.SlabScheduler is sess.SlabScheduler
-    with pytest.warns(DeprecationWarning):
-        assert legacy.run_sessions is sess.run_sessions
-    with pytest.warns(DeprecationWarning):
-        assert legacy.QOS_POLICIES == sess.QOS_POLICIES
-    with pytest.raises(AttributeError):
-        legacy.definitely_not_a_name
-
-
-def test_tickplan_tuple_unpack_deprecated():
-    """Unpacking a TickPlan as the legacy (frames, valid, reset) 3-tuple
-    still works but emits a DeprecationWarning (it silently drops the hold
-    mask and the snapshot/restore orders)."""
-    sched = _mini_sched(slots=1)
-    sched.submit(sess.SessionRequest(sid=0, arrival=0,
-                                     clip=np.zeros((2, V, C), np.float32)))
-    tp = sched.tick_inputs(0, 0.0)
-    with pytest.warns(DeprecationWarning, match="TickPlan"):
-        frames, valid, reset = tp
-    np.testing.assert_array_equal(frames, tp.frames)
-    np.testing.assert_array_equal(valid, tp.valid)
-    np.testing.assert_array_equal(reset, tp.reset)
-
-
 def test_run_sessions_end_to_end():
     """The serve --sessions path (two-stream ensemble, Poisson traffic):
     every session completes, metrics are populated, logits finite."""

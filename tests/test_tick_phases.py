@@ -3,9 +3,11 @@
 ``tick()`` runs as contiguous phases — feed, stage, dispatch, a readback
 when one is due, drain — each a profiler span and a ``phase_s`` entry;
 ``poll(wait=True)`` and ``metrics()`` add to ``readback`` too.  Locked
-here, on the reference backend with two slots, for the fused and the
-legacy tick paths over a one-topology and a mixed-topology slab, through a
-scripted run with a preemption, a restore and finishing sessions:
+here, on the reference backend with two slots, under each QoS policy over
+a one-topology and a mixed-topology slab, through a scripted run with
+finishing sessions: under ``fifo`` every tick is event-free, under
+``preempt`` a preemption and a restore run through the event tick, and
+under ``deadline`` a queued session misses its deadline:
 
 * ``wall_host_s`` and ``wall_device_s`` are sums of the phases;
 * every tick opens its spans in order, none nested in another;
@@ -18,7 +20,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.serving import GcnService
+from repro.serving import QOS_POLICIES, GcnService
 from repro.serving.service import HOST_PHASES, TICK_PHASES
 
 CFG = get_config("agcn-2s", reduced=True)
@@ -61,26 +63,29 @@ TOPOLOGIES = {"single": ("ntu25",), "mixed": ("ntu25", "ntu50")}
 
 
 @pytest.fixture(scope="module", params=[
-    (fused, topo) for fused in (True, False) for topo in TOPOLOGIES],
-    ids=lambda p: f"{'fused' if p[0] else 'legacy'}-{p[1]}")
+    (qos, topo) for qos in QOS_POLICIES for topo in TOPOLOGIES],
+    ids=lambda p: f"{p[0]}-{p[1]}")
 def scripted(request):
     """X (priority 0) and Y (priority 1, the second topology) fill both
-    slots; Z (priority 2) arrives at tick 5 and preempts X, which is
-    restored when a slot frees; the run goes on until every session has
-    finished.  Returns the service, each tick's span log, the compiles
-    counted while ticking and the host wall time around the calls."""
-    fused, topo = request.param
+    slots; Z (priority 2) arrives at tick 5.  Under ``preempt`` Z evicts
+    X, which is restored when a slot frees; under ``fifo`` Z queues for a
+    free slot; under ``deadline`` Z's deadline (tick 6) passes while it
+    queues.  The run goes on until no session is left.  Returns the
+    service, each tick's span log and whether it carried snapshot or
+    restore events, the compiles counted while ticking and the host wall
+    time around the calls."""
+    qos, topo = request.param
     topologies = TOPOLOGIES[topo]
     first, second = topologies[0], topologies[-1]
-    svc = GcnService(CFG, backend="reference", qos="preempt",
-                     capacity_tiers=(2,), fused=fused,
-                     topologies=topologies, seed=0)
+    svc = GcnService(CFG, backend="reference", qos=qos,
+                     capacity_tiers=(2,), topologies=topologies, seed=0)
     rec = SpanRecorder()
     svc._span = rec
     rng = np.random.default_rng(3)
 
-    def arrive(priority, topology, frames):
-        h = svc.open_session(priority=priority, topology=topology)
+    def arrive(priority, topology, frames, deadline=None):
+        h = svc.open_session(priority=priority, topology=topology,
+                             deadline=deadline)
         V = svc._topos[topology].num_joints
         svc.submit_clip(h, rng.standard_normal((frames, V, C))
                         .astype(np.float32))
@@ -92,14 +97,17 @@ def scripted(request):
     try:
         while True:
             if svc.now == 5:
-                handles.append(arrive(2, first, 8))
+                handles.append(arrive(2, first, 8,
+                                      6 if qos == "deadline" else None))
             if svc.idle():
                 break
             start = len(rec.log)
+            events = svc.sched.preemptions + svc.sched.restores
             t0 = time.monotonic()
             svc.tick()
             wall += time.monotonic() - t0
-            per_tick.append(rec.log[start:])
+            events = svc.sched.preemptions + svc.sched.restores > events
+            per_tick.append((rec.log[start:], events))
         start = len(rec.log)
         t0 = time.monotonic()
         svc.poll(handles[0], wait=True)
@@ -108,15 +116,20 @@ def scripted(request):
         outside = rec.log[start:]
     finally:
         _COMPILES["on"] = False
-    return {"svc": svc, "fused": fused, "per_tick": per_tick,
+    return {"svc": svc, "qos": qos, "per_tick": per_tick,
             "outside": outside, "compiles": _COMPILES["n"], "wall": wall,
             "handles": handles}
 
 
 def test_wall_counters_are_sums_of_the_phases(scripted):
-    svc = scripted["svc"]
-    assert svc.sched.preemptions >= 1 and svc.sched.restores >= 1
-    assert all(svc.poll(h).state == "done" for h in scripted["handles"])
+    svc, qos = scripted["svc"], scripted["qos"]
+    if qos == "preempt":
+        assert svc.sched.preemptions >= 1 and svc.sched.restores >= 1
+    else:
+        assert svc.sched.preemptions == 0 and svc.sched.restores == 0
+    # Z, the last handle, misses its deadline under "deadline"
+    want = ["done"] * 3 if qos != "deadline" else ["done", "done", "missed"]
+    assert [svc.poll(h).state for h in scripted["handles"]] == want
     ph = svc.phase_s
     assert set(ph) == set(TICK_PHASES)
     assert svc.wall_host_s == pytest.approx(
@@ -137,27 +150,29 @@ def test_wall_counters_are_sums_of_the_phases(scripted):
 def test_each_tick_opens_its_spans_in_order(scripted):
     host = ["svc." + p for p in HOST_PHASES]
     with_readback = host[:3] + ["svc.readback"] + host[3:]
-    forced = 0
-    for log in scripted["per_tick"]:
+    forced = event_ticks = 0
+    for log, events in scripted["per_tick"]:
         # spans never nest: each enter is closed before the next opens
         assert log[0::2] == [("enter", n) for _, n in log[0::2]]
         assert log[1::2] == [("exit", n) for _, n in log[0::2]]
         names = [n for _, n in log[0::2]]
         assert names in (host, with_readback)
         forced += names == with_readback
-    if scripted["fused"]:
-        # only ticks on which a session finishes force the readback
-        assert 0 < forced < len(scripted["per_tick"])
+        event_ticks += events
+    # only ticks on which a session finishes force the readback, event
+    # ticks and plain ticks alike
+    assert 0 < forced < len(scripted["per_tick"])
+    if scripted["qos"] == "preempt":
+        assert event_ticks > 0
     else:
-        assert forced == len(scripted["per_tick"])
+        assert event_ticks == 0
     # poll(wait=True) and metrics() after the last tick find the logits
     # already forced, so they open no readback span
     assert scripted["outside"] == []
 
 
 def test_poll_wait_times_its_readback():
-    svc = GcnService(CFG, backend="reference", capacity_tiers=(2,),
-                     fused=True, seed=0)
+    svc = GcnService(CFG, backend="reference", capacity_tiers=(2,), seed=0)
     rec = SpanRecorder()
     svc._span = rec
     h = svc.open_session()
